@@ -84,6 +84,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(ALL))
     args = ap.parse_args()
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     names = list(ALL) if not args.only else args.only.split(",")
     started_at = time.time()
     for name in names:

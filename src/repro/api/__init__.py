@@ -19,6 +19,7 @@ Legacy entry points (``repro.core.mis2.mis2``, ``solvers.amg.AGGREGATORS``,
 """
 from .backend import (
     Backend,
+    InexactFloat64Error,
     accelerator_present,
     default_interpret,
     get_default_backend,
@@ -69,7 +70,8 @@ __all__ = [
     # graph handle
     "Graph", "as_graph", "as_ell_graph", "as_csr_graph",
     # backend policy
-    "Backend", "accelerator_present", "default_interpret",
+    "Backend", "InexactFloat64Error", "accelerator_present",
+    "default_interpret",
     "get_default_backend", "set_default_backend", "using_backend",
     # engine registry
     "register_engine", "get_engine", "get_engine_spec", "list_engines",

@@ -125,15 +125,50 @@ def default_misk_engine(backend: Optional["Backend"] = None) -> str:
     return "dense"
 
 
-def default_multilevel_engine(backend: Optional["Backend"] = None) -> str:
+#: platforms whose float64 arithmetic is not IEEE.  XLA emulates f64 on
+#: the TPU: on a v5e, add, multiply, divide and sqrt of random operands
+#: differ from numpy's correctly rounded results in over 70% of cases
+#: (relative error up to 1.5e-10 for add).  The resident AMG setup does
+#: its prolongator and Galerkin arithmetic in f64, so there it cannot
+#: reproduce the host engine's hierarchy bit for bit.
+INEXACT_F64_PLATFORMS = ("tpu",)
+
+
+class InexactFloat64Error(NotImplementedError):
+    """The resident AMG setup was asked to run on a platform whose f64
+    arithmetic is not IEEE (:data:`INEXACT_F64_PLATFORMS`): its hierarchy
+    would differ from the host engine's, so it is refused rather than
+    returned as a different answer.  Use ``engine="host"`` (the
+    ``engine=None`` choice there)."""
+
+
+def check_exact_f64(engine: str, backend: Optional["Backend"] = None) -> None:
+    """Raise :class:`InexactFloat64Error` for the resident AMG setup on a
+    platform without IEEE float64."""
+    platform = backend_platform(backend)
+    if engine == "resident" and platform in INEXACT_F64_PLATFORMS:
+        raise InexactFloat64Error(
+            f"multilevel engine 'resident' cannot build a host-identical "
+            f"AMG hierarchy on {platform!r}: XLA emulates float64 there "
+            f"and does not round like IEEE; use engine='host'")
+
+
+def default_multilevel_engine(backend: Optional["Backend"] = None,
+                              kind: str = "amg") -> str:
     """``multilevel`` auto-selection (``engine=None``): the device-resident
     setup (on-device prolongator/Galerkin/packing, zero matrix-sized host
     syncs) on accelerators; the host scipy/numpy path on CPU hosts, where
     the round-trips are address-space copies.  Both engines produce
-    digest-identical hierarchies.  Like :func:`default_mis2_engine`, the
+    digest-identical hierarchies — except the AMG setup (``kind="amg"``)
+    on a platform without IEEE float64 (the TPU), where only ``host`` can,
+    so ``host`` is chosen there.  Like :func:`default_mis2_engine`, the
     rule honors ``Backend(device=...)`` per request (the device's platform
     wins over the process default)."""
-    return "resident" if backend_accelerator(backend) else "host"
+    if not backend_accelerator(backend):
+        return "host"
+    if kind == "amg" and backend_platform(backend) in INEXACT_F64_PLATFORMS:
+        return "host"
+    return "resident"
 
 
 @dataclass(frozen=True)
@@ -147,9 +182,12 @@ class Backend:
     axis: Any = None                   # mesh axis name (or tuple) to shard on
 
     def resolve_interpret(self) -> bool:
+        """Interpret Pallas kernels iff requests under this backend run
+        off-accelerator — a pinned ``device`` decides, like engine
+        auto-selection (a CPU-pinned request on a TPU host interprets)."""
         if self.interpret is not None:
             return bool(self.interpret)
-        return default_interpret()
+        return not backend_accelerator(self)
 
     def resolve_mesh(self):
         """(mesh, axis) for the distributed engines.

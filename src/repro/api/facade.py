@@ -355,7 +355,10 @@ def amg_setup(matrix, *, aggregation: str = "two_phase",
     syncs).  Both produce digest-identical hierarchies (per-level ``A_l``
     ELL digests on the result, labels/colors from the shared aggregation
     and coloring fixed points).  ``engine=None`` auto-selects ``resident``
-    on accelerators, ``host`` on CPU hosts.
+    on accelerators with IEEE float64, ``host`` on CPU hosts and on the
+    TPU, where ``resident`` raises
+    :class:`~repro.api.backend.InexactFloat64Error` (XLA emulates f64
+    there, so the hierarchy could not match the host engine's).
 
     ``coarse_dtype`` controls the dense coarsest-level factorization
     (default: float64 on CPU hosts, float32 on accelerators);
@@ -368,12 +371,13 @@ def amg_setup(matrix, *, aggregation: str = "two_phase",
     SpMV kernel (``kernels.spmv_ell.spmv_t``), halving transfer-operator
     memory at the cost of a scatter per restriction.
     """
-    from .backend import default_multilevel_engine
+    from .backend import check_exact_f64, default_multilevel_engine
 
     be = resolve_backend(backend)
-    gh = _prepare(matrix, be)
     if engine is None:
         engine = default_multilevel_engine(be)
+    check_exact_f64(engine, be)
+    gh = _prepare(matrix, be)
     fn = get_engine("multilevel", engine)
     return _traced(
         "amg_setup", engine, be,
@@ -429,7 +433,7 @@ def cluster_gs_setup(matrix, *, aggregation: str = "two_phase",
     be = resolve_backend(backend)
     gh = _prepare(matrix, be)
     if engine is None:
-        engine = default_multilevel_engine(be)
+        engine = default_multilevel_engine(be, kind="cluster_gs")
     fn = get_engine("multilevel", engine)
 
     def _build(out, dt):
@@ -462,12 +466,13 @@ def amg_setup_batch(matrices, *, aggregation: str = "two_phase",
     the selected multilevel engine.  Per-graph hierarchies are
     digest-identical to ``amg_setup(g, ...)``."""
     from ..batch.pipeline import _amg_setup_batch_impl
-    from .backend import default_multilevel_engine
+    from .backend import check_exact_f64, default_multilevel_engine
 
     be = resolve_backend(backend)
-    batch = _prepare_batch(matrices, be)
     if engine is None:
         engine = default_multilevel_engine(be)
+    check_exact_f64(engine, be)
+    batch = _prepare_batch(matrices, be)
 
     def _wrap(hierarchies, dt):
         per = dt / max(1, len(hierarchies))

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from .._compat import warn_deprecated
 from ..graphs.csr import ELLGraph
 from ..graphs.handle import as_graph
@@ -63,7 +64,7 @@ class AggregationResult:
 
 def _join_rows(neighbors_rows: jnp.ndarray, root_label_global: jnp.ndarray):
     """Rowwise body of :func:`_join_adjacent_root` over a row block."""
-    cand = root_label_global[neighbors_rows]   # [rows, D] (self-pad: own label)
+    cand = xla_ops.gather_rows(root_label_global, neighbors_rows)   # [rows, D]
     lab = jnp.min(cand, axis=1)
     return jnp.where(lab == INT32_MAX, jnp.int32(-1), lab)
 
@@ -82,7 +83,7 @@ def _join_adjacent_root(neighbors: jnp.ndarray, root_label: jnp.ndarray):
 def _count_unagg_rows(neighbors_rows, mask_rows, row_ids, labels_global):
     """Rowwise body of :func:`_count_unagg_neighbors` over a row block."""
     real = mask_rows & (neighbors_rows != row_ids[:, None])
-    unagg = labels_global[neighbors_rows] < 0
+    unagg = xla_ops.gather_rows(labels_global, neighbors_rows) < 0
     return jnp.sum(real & unagg, axis=1)
 
 
@@ -95,13 +96,18 @@ def _count_unagg_neighbors(neighbors, mask, labels):
 
 
 def _phase3_keys(labels_n, valid, aggsize):
-    """Per-slot (coupling, aggsize, label) selection keys (lower = better);
-    coupling negated so a single lexicographic min picks max coupling."""
-    d = labels_n.shape[1]
-    coupling = jnp.zeros(labels_n.shape, jnp.int32)
-    for k in range(d):
-        same = (labels_n == labels_n[:, k:k + 1]) & valid[:, k:k + 1] & valid
-        coupling = coupling + same.astype(jnp.int32)
+    """Per-slot (coupling, aggsize) selection keys over slot-major
+    ``[D, R]`` tiles: coupling = # valid slots of the row holding the
+    same label.  The slot loop is a ``fori_loop`` so the program size
+    does not grow with the ELL width (a hub row makes D large)."""
+    def add_slot(k, coupling):
+        lab_k = jax.lax.dynamic_index_in_dim(labels_n, k, keepdims=False)
+        val_k = jax.lax.dynamic_index_in_dim(valid, k, keepdims=False)
+        same = (labels_n == lab_k) & val_k & valid
+        return coupling + same.astype(jnp.int32)
+
+    coupling = jax.lax.fori_loop(0, labels_n.shape[0], add_slot,
+                                 jnp.zeros(labels_n.shape, jnp.int32))
     size_n = aggsize[jnp.clip(labels_n, 0, aggsize.shape[0] - 1)]
     return coupling, size_n
 
@@ -110,25 +116,28 @@ def _phase3_rows(neighbors_rows, mask_rows, row_ids, labels_global,
                  labels_rows, aggsize):
     """Rowwise body of :func:`_phase3_join` over a row block (neighbor
     labels looked up in ``labels_global``, joins applied to
-    ``labels_rows``)."""
-    labels_n = labels_global[neighbors_rows]         # tentative labels
-    valid = mask_rows & (neighbors_rows != row_ids[:, None]) & (labels_n >= 0)
+    ``labels_rows``).  The slots run down axis 0 (``[D, R]``) so each
+    per-slot step is one lane-dense ``[R]`` vector on the TPU."""
+    nbrs = neighbors_rows.T
+    labels_n = labels_global[nbrs]                   # tentative labels
+    valid = mask_rows.T & (nbrs != row_ids[None, :]) & (labels_n >= 0)
     coupling, size_n = _phase3_keys(labels_n, valid, aggsize)
-    d = neighbors_rows.shape[1]
+    # per-slot keys of (-coupling, size, label); invalid slots last
+    keys_c = jnp.where(valid, coupling, -1)
+    keys_l = jnp.where(valid, labels_n, INT32_MAX)
 
-    # lexicographic argmin over slots of (-coupling, size, label); invalid last
-    best_c = jnp.where(valid[:, 0], coupling[:, 0], -1)
-    best_s = size_n[:, 0]
-    best_l = jnp.where(valid[:, 0], labels_n[:, 0], INT32_MAX)
-    for j in range(1, d):
-        cj = jnp.where(valid[:, j], coupling[:, j], -1)
-        sj = size_n[:, j]
-        lj = jnp.where(valid[:, j], labels_n[:, j], INT32_MAX)
+    def pick(j, best):
+        """Lexicographic argmin step over slot ``j`` (slot order kept)."""
+        best_c, best_s, best_l = best
+        cj, sj, lj = (jax.lax.dynamic_index_in_dim(x, j, keepdims=False)
+                      for x in (keys_c, size_n, keys_l))
         better = (cj > best_c) | ((cj == best_c) & ((sj < best_s) |
                  ((sj == best_s) & (lj < best_l))))
-        best_c = jnp.where(better, cj, best_c)
-        best_s = jnp.where(better, sj, best_s)
-        best_l = jnp.where(better, lj, best_l)
+        return (jnp.where(better, cj, best_c), jnp.where(better, sj, best_s),
+                jnp.where(better, lj, best_l))
+
+    best_c, _, best_l = jax.lax.fori_loop(
+        1, nbrs.shape[0], pick, (keys_c[0], size_n[0], keys_l[0]))
     joined = (best_c > 0) & (best_l != INT32_MAX)
     return jnp.where((labels_rows < 0) & joined, best_l, labels_rows)
 
@@ -192,7 +201,7 @@ def _phase2_join_resident(neighbors, mask, labels, in_set2, nagg,
     row_ids = jnp.arange(v, dtype=neighbors.dtype)
     n_unagg = _count_unagg_rows(neighbors, mask, row_ids, labels)
     roots2 = in_set2 & (n_unagg >= min_secondary)
-    agg_ids2 = nagg + jnp.cumsum(roots2.astype(jnp.int32)) - 1
+    agg_ids2 = nagg + xla_ops.cumsum(roots2.astype(jnp.int32)) - 1
     rl2 = jnp.where(roots2, agg_ids2, INT32_MAX).astype(jnp.int32)
     adj2 = _join_rows(neighbors, rl2)
     newly = (labels < 0) & (adj2 >= 0)
@@ -321,7 +330,7 @@ def _phase2_join_resident_hybrid(slices, spill_rows, spill_seg, spill_cols,
                                    spill_seg, num_segments=h)
         n_unagg = n_unagg.at[spill_rows].set(n_sp)
     roots2 = in_set2 & (n_unagg >= min_secondary)
-    agg_ids2 = nagg + jnp.cumsum(roots2.astype(jnp.int32)) - 1
+    agg_ids2 = nagg + xla_ops.cumsum(roots2.astype(jnp.int32)) - 1
     rl2 = jnp.where(roots2, agg_ids2, INT32_MAX).astype(jnp.int32)
     adj2 = _hybrid_join_labels(slices, spill_rows, spill_seg, spill_cols, rl2)
     newly = (labels < 0) & (adj2 >= 0)
@@ -351,7 +360,7 @@ def _phase3_spill(spill_rows, spill_seg, spill_cols, labels, aggsize):
     start = jnp.concatenate([
         jnp.ones(1, dtype=bool),
         (seg_s[1:] != seg_s[:-1]) | (lab_s[1:] != lab_s[:-1])])
-    run_id = jnp.cumsum(start.astype(jnp.int32)) - 1
+    run_id = xla_ops.cumsum(start.astype(jnp.int32)) - 1
     run_len = jax.ops.segment_sum(jnp.ones(s, jnp.int32), run_id,
                                   num_segments=s)
     c_e = jnp.where(lab_s < INT32_MAX, run_len[run_id], -1)

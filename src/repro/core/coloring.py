@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from .._compat import warn_deprecated
 from ..graphs.handle import as_ell_graph
 from .hashing import priorities_xorshift_star
@@ -45,13 +46,13 @@ def _color_round_rows(neighbors_rows, mask_rows, row_ids, colors, prio):
     own = colors[row_ids]
     # local-min among uncolored real neighbors (excluding self)
     real = mask_rows & (neighbors_rows != row_ids[:, None])
-    pn = prio[neighbors_rows]
-    un = uncolored[neighbors_rows]
+    pn = xla_ops.gather_rows(prio, neighbors_rows)
+    un = xla_ops.gather_rows(uncolored, neighbors_rows)
     contender = real & un
     is_min = jnp.all(jnp.where(contender, prio[row_ids][:, None] < pn, True),
                      axis=1)
     # forbidden colors bitmask (two uint32 words -> up to 64 colors)
-    cn = colors[neighbors_rows]
+    cn = xla_ops.gather_rows(colors, neighbors_rows)
     has = real & (cn >= 0)
     lo_bits = jnp.where(has & (cn < 32),
                         jnp.uint32(1) << jnp.clip(cn, 0, 31).astype(jnp.uint32),

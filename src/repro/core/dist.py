@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import xla_ops
 from ..graphs.csr import ELLGraph
 from ..graphs.handle import as_ell_graph, as_graph
 from ..obs import metrics as _OBS
@@ -57,27 +58,15 @@ from .hashing import PRIORITY_FNS
 from .mis2 import Mis2Options, Mis2Result
 from .tuples import IN, OUT, id_bits, is_undecided, pack
 
-try:                                   # jax >= 0.5 promotes it to jax.*
-    _shard_map_raw = jax.shard_map
-    _NOREP_KWARGS = ({"check_vma": False}, {"check_rep": False}, {})
-except AttributeError:                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-    # the while_loop fixpoint has no replication rule in 0.4.x shard_map
-    _NOREP_KWARGS = ({"check_rep": False}, {})
-
 TUPLE_BYTES = 4                        # one packed §V-C tuple
 
 DRYRUN_GRAPH_DIR = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun_graph"
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    for kw in _NOREP_KWARGS:
-        try:
-            return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **kw)
-        except TypeError:              # kwarg renamed across jax versions
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
+    # the while_loop fixed point carries no varying-manual-axes rule
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _resolve_mesh(mesh: Optional[Mesh], axis):
@@ -157,7 +146,7 @@ def _mis2_local_fixpoint(neighbors_local, active_local, axis: str,
     # fixed-point body, so steady-state traffic is exactly the T (+ M)
     # gathers that collective_bytes_per_iteration() models
     a_global = jax.lax.all_gather(active_local, axis, tiled=True)
-    an = a_global[neighbors_local]                                 # [Vp, D]
+    an = xla_ops.gather_rows(a_global, neighbors_local)                     # [Vp, D]
 
     def cond(state):
         t_local, it = state
@@ -173,16 +162,16 @@ def _mis2_local_fixpoint(neighbors_local, active_local, axis: str,
         t_global = jax.lax.all_gather(t_local, axis, tiled=True)   # [V]
         if single_gather:
             # recompute M for ALL vertices locally: no second gather
-            tn_all = t_global[neighbors_global]                    # [V, D]
+            tn_all = xla_ops.gather_rows(t_global, neighbors_global)        # [V, D]
             m_global = jnp.min(tn_all, axis=1)
             m_global = jnp.where(m_global == IN, OUT, m_global)
         else:
-            tn = t_global[neighbors_local]                         # [Vp, D]
+            tn = xla_ops.gather_rows(t_global, neighbors_local)             # [Vp, D]
             m_local = jnp.min(tn, axis=1)
             m_local = jnp.where(m_local == IN, OUT, m_local)
             # collective 2: global M for the distance-2 decision
             m_global = jax.lax.all_gather(m_local, axis, tiled=True)
-        mn = m_global[neighbors_local]
+        mn = xla_ops.gather_rows(m_global, neighbors_local)
         any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
         all_eq = jnp.all(jnp.where(an, mn, t_local[:, None]) == t_local[:, None],
                          axis=1)

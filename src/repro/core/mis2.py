@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from .._compat import warn_deprecated
 from ..graphs.handle import as_graph
 from ..obs import metrics as _OBS
@@ -140,12 +141,12 @@ def mis2_dense_fixed_point(neighbors: jnp.ndarray, active: jnp.ndarray,
         # refresh row (§V-A)
         t = jnp.where(und, pack(prio_fn(it, vids), vids, b), t)
         # refresh column: closed-neighborhood min (§V-D layout)
-        tn = t[neighbors]                       # [V, D]
+        tn = xla_ops.gather_rows(t, neighbors)           # [V, D]
         m = jnp.min(tn, axis=1)
         m = jnp.where(m == IN, OUT, m)          # IN-adjacent poison
         # decide (distance-2 via neighbors' minima)
-        mn = m[neighbors]                       # [V, D]
-        an = active[neighbors]
+        mn = xla_ops.gather_rows(m, neighbors)           # [V, D]
+        an = xla_ops.gather_rows(active, neighbors)
         any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
         all_eq = jnp.all(jnp.where(an, mn, t[:, None]) == t[:, None], axis=1)
         t = jnp.where(und & any_out, OUT, t)
@@ -223,10 +224,10 @@ def mis2_repair_fixed_point(neighbors: jnp.ndarray, t_init: jnp.ndarray,
         und = is_undecided(t)
         live = jnp.any(und)
         t = jnp.where(und, pack(prio_fn(it, vids), vids, b), t)
-        tn = t[neighbors]
+        tn = xla_ops.gather_rows(t, neighbors)
         m = jnp.min(tn, axis=1)
         m = jnp.where(m == IN, OUT, m)
-        mn = m[neighbors]
+        mn = xla_ops.gather_rows(m, neighbors)
         any_out = jnp.any(mn == OUT, axis=1)
         all_eq = jnp.all(mn == t[:, None], axis=1)
         t = jnp.where(und & any_out, OUT, t)
@@ -254,8 +255,8 @@ def lexfirst_violations(neighbors: jnp.ndarray, in_set: jnp.ndarray,
     vertices to reactivate.
     """
     pin = jnp.where(in_set, p, OUT)
-    m1 = jnp.minimum(jnp.min(pin[neighbors], axis=1), pin)
-    m2 = jnp.minimum(jnp.min(m1[neighbors], axis=1), m1)
+    m1 = jnp.minimum(jnp.min(xla_ops.gather_rows(pin, neighbors), axis=1), pin)
+    m2 = jnp.minimum(jnp.min(xla_ops.gather_rows(m1, neighbors), axis=1), m1)
     return ~jnp.where(in_set, m2 == p, m2 < p)
 
 
@@ -367,10 +368,8 @@ def _refresh_rows_packed(t, wl1, it, priority: str, b: int):
 
 @jax.jit
 def _refresh_cols_packed_ell(t, m, wl2, neighbors):
-    v = neighbors.shape[0]
-    rows = jnp.clip(wl2, 0, v - 1)
-    tn = t[neighbors[rows]]
-    mv = jnp.min(tn, axis=1)
+    tn = t[xla_ops.slot_major_rows(neighbors, wl2)]     # [D, W]
+    mv = jnp.min(tn, axis=0)
     mv = jnp.where(mv == IN, OUT, mv)
     return m.at[wl2].set(mv, mode="drop")
 
@@ -378,13 +377,12 @@ def _refresh_cols_packed_ell(t, m, wl2, neighbors):
 @jax.jit
 def _decide_packed_ell(t, m, wl1, neighbors, active):
     v = neighbors.shape[0]
-    rows = jnp.clip(wl1, 0, v - 1)
-    nb = neighbors[rows]
+    nb = xla_ops.slot_major_rows(neighbors, wl1)        # [D, W]
     mn = m[nb]
     an = active[nb]
-    tv = t[rows]
-    any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
-    all_eq = jnp.all(jnp.where(an, mn, tv[:, None]) == tv[:, None], axis=1)
+    tv = t[jnp.clip(wl1, 0, v - 1)]
+    any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=0)
+    all_eq = jnp.all(jnp.where(an, mn, tv[None, :]) == tv[None, :], axis=0)
     newt = jnp.where(any_out, OUT, jnp.where(all_eq, IN, tv))
     newt = jnp.where(is_undecided(tv), newt, tv)
     return t.at[wl1].set(newt, mode="drop")
@@ -440,7 +438,7 @@ def _refresh_cols_unpacked_ell(ts, tr, ti, ms, mr, mi, wl2, neighbors):
     v = neighbors.shape[0]
     rows = jnp.clip(wl2, 0, v - 1)
     nb = neighbors[rows]                      # [W, D]
-    cs, cr, ci = ts[nb], tr[nb], ti[nb]
+    cs, cr, ci = (xla_ops.gather_rows(x, nb) for x in (ts, tr, ti))
     bs, br, bi = cs[:, 0], cr[:, 0], ci[:, 0]
     for j in range(1, nb.shape[1]):           # unrolled lexicographic min
         lt = _lex_lt(cs[:, j], cr[:, j], ci[:, j], bs, br, bi)
@@ -483,8 +481,8 @@ def _decide_unpacked_ell(ts, tr, ti, ms, mr, mi, wl1, neighbors, active):
     v = neighbors.shape[0]
     rows = jnp.clip(wl1, 0, v - 1)
     nb = neighbors[rows]
-    an = active[nb]
-    cs, cr, ci = ms[nb], mr[nb], mi[nb]
+    an = xla_ops.gather_rows(active, nb)
+    cs, cr, ci = (xla_ops.gather_rows(x, nb) for x in (ms, mr, mi))
     tvs, tvr, tvi = ts[rows], tr[rows], ti[rows]
     any_out = jnp.any(an & (cs == S_OUT), axis=1)
     eq = (cs == S_UND) & (cr == tvr[:, None]) & (ci == tvi[:, None])
@@ -634,7 +632,7 @@ def compact_worklist(mask: jnp.ndarray):
     """
     v = mask.shape[0]
     vids = jnp.arange(v, dtype=jnp.int32)
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    pos = xla_ops.cumsum(mask.astype(jnp.int32)) - 1
     wl = jnp.full(v, v, dtype=jnp.int32)
     wl = wl.at[jnp.where(mask, pos, v)].set(vids, mode="drop")
     return wl, jnp.sum(mask, dtype=jnp.int32)
@@ -645,7 +643,7 @@ def compact_worklist(mask: jnp.ndarray):
 def _resident_ell_fixed_point(neighbors, active, *, priority: str,
                               packed: bool, max_iters: int, b: int,
                               use_pallas: bool = False,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """Device-resident compacted fixed point, ELL layout.
 
     Identical per-round passes to the host-driven driver (same step
@@ -657,11 +655,15 @@ def _resident_ell_fixed_point(neighbors, active, *, priority: str,
     the §V-A rank packing is recomputed on the fly from the gathered
     neighbor ids, so no separate refresh_rows pass runs and each round
     reads the ELL rows once per pass, with the live ``count`` feeding the
-    ``pl.when`` block-skip logic.
+    ``pl.when`` block-skip logic.  ``interpret=None`` defers to the
+    platform policy (``kernels._interpret.resolve_interpret``).
     """
     v = neighbors.shape[0]
     if use_pallas:
+        from ..kernels._interpret import resolve_interpret
         from ..kernels.minprop_ell import ops as minprop_ops
+
+        interpret = resolve_interpret(interpret)
 
     if packed:
         t0 = jnp.where(active, jnp.uint32(1), U32MAX)
@@ -819,13 +821,10 @@ def _mis2_resident_impl(graph, active: Optional[np.ndarray] = None,
     with _obs_span("mis2.resident_fixed_point", layout=options.layout,
                    pallas=pallas, packed=options.packed, v=v) as sp:
         if options.layout == "ell":
-            if pallas:
-                from ..kernels._interpret import resolve_interpret
-                interpret = resolve_interpret(interpret)
             t, it, n1 = _resident_ell_fixed_point(
                 gh.ell.neighbors, active_j, priority=options.priority,
                 packed=options.packed, max_iters=options.max_iters, b=b,
-                use_pallas=pallas, interpret=bool(interpret))
+                use_pallas=pallas, interpret=interpret)
         else:
             edge_rows, edge_cols = gh.csr_edges
             t, it, n1 = _resident_csr_fixed_point(

@@ -52,7 +52,7 @@ HYBRID_ROW_BYTES = "mis2.hybrid_row_bytes"
     "priority", "max_iters", "b", "interpret"))
 def _hybrid_fixed_point(slices, spill_rows, spill_seg, spill_cols, active,
                         *, priority: str, max_iters: int, b: int,
-                        interpret: bool = True):
+                        interpret: bool):
     """One jitted while_loop over global [V] state; rounds unroll over the
     slices (static: one compiled Pallas body per slice shape) and close
     with the spill segment passes.  Returns ``(t, iterations, undecided,
@@ -83,9 +83,8 @@ def _hybrid_fixed_point(slices, spill_rows, spill_seg, spill_cols, active,
         for i, sl in enumerate(slices):
             wl2, n2 = compact_worklist(live[sl.rows])
             m = minprop_ops.sliced_refresh_columns(
-                t, m, sl.rows, sl.neighbors.reshape(-1), wl2, n2, it,
-                priority=priority, b=b, d=sl.neighbors.shape[1],
-                interpret=interpret)
+                t, m, sl.rows, sl.neighbors, wl2, n2, it,
+                priority=priority, b=b, interpret=interpret)
             acc = acc.at[i].add(n2)
         if h > 0:
             m = minprop_ops.spill_refresh_columns(
@@ -97,9 +96,8 @@ def _hybrid_fixed_point(slices, spill_rows, spill_seg, spill_cols, active,
         for i, sl in enumerate(slices):
             wl1, n1_i = compact_worklist(und[sl.rows])
             t = minprop_ops.sliced_decide(
-                t, m, active, sl.rows, sl.neighbors.reshape(-1), wl1, n1_i,
-                it, priority=priority, b=b, d=sl.neighbors.shape[1],
-                interpret=interpret)
+                t, m, active, sl.rows, sl.neighbors, wl1, n1_i, it,
+                priority=priority, b=b, interpret=interpret)
             acc = acc.at[i].add(n1_i)
         if h > 0:
             t = minprop_ops.spill_decide(

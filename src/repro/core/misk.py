@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from .._compat import warn_deprecated
 from ..graphs.handle import as_ell_graph
 from .hashing import PRIORITY_FNS
@@ -45,13 +46,14 @@ def _misk_fixpoint(neighbors, k: int, priority: str, max_iters: int):
         # k-fold closed-neighborhood min
         m = t
         for _ in range(k):
-            m = jnp.min(m[neighbors], axis=1)
+            m = jnp.min(xla_ops.gather_rows(m, neighbors), axis=1)
         new_in = und & (m == t)
         t = jnp.where(new_in, IN, t)
         # propagate OUT-ness k hops from IN vertices
         near_in = (t == IN)
         for _ in range(k):
-            near_in = jnp.any(near_in[neighbors], axis=1) | near_in
+            near_in = jnp.any(xla_ops.gather_rows(near_in, neighbors),
+                              axis=1) | near_in
         t = jnp.where(is_undecided(t) & near_in, OUT, t)
         return t, it + 1
 
@@ -103,13 +105,14 @@ def _misk_resident_fixpoint(neighbors, k: int, priority: str, max_iters: int):
         # k-fold closed-neighborhood min
         m = t
         for _ in range(k):
-            m = jnp.min(m[neighbors], axis=1)
+            m = jnp.min(xla_ops.gather_rows(m, neighbors), axis=1)
         new_in = is_undecided(t) & (m == t)
         t = jnp.where(new_in, IN, t)
         # propagate OUT-ness k hops from IN vertices
         near_in = (t == IN)
         for _ in range(k):
-            near_in = jnp.any(near_in[neighbors], axis=1) | near_in
+            near_in = jnp.any(xla_ops.gather_rows(near_in, neighbors),
+                              axis=1) | near_in
         t = jnp.where(is_undecided(t) & near_in, OUT, t)
         wl, n = compact_worklist(is_undecided(t))
         return t, wl, n, it + jnp.uint32(1)

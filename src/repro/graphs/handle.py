@@ -109,6 +109,9 @@ class Graph:
         t0 = time.perf_counter()
         try:
             yield
+            if self._cache.get("device") is not None:
+                # a placed handle keeps every later format on its device
+                self.place(self._cache["device"])
         finally:
             dt = time.perf_counter() - t0
             self._counts[name] = self._counts.get(name, 0) + 1

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from .csr import CSRGraph, CSRMatrix, ELLGraph, ELLMatrix, csr_from_coo
 
 
@@ -19,7 +20,7 @@ from .csr import CSRGraph, CSRMatrix, ELLGraph, ELLMatrix, csr_from_coo
 
 def spmv_ell(m: ELLMatrix, x: jnp.ndarray) -> jnp.ndarray:
     """y = A @ x with A in ELL form. Padding has vals == 0 so no mask needed."""
-    gathered = x[m.cols]                      # [V, D]
+    gathered = xla_ops.gather_rows(x, m.cols)          # [V, D]
     return jnp.sum(m.vals * gathered, axis=1)
 
 
@@ -40,17 +41,17 @@ def spmv_csr_segment(m: CSRMatrix, x: jnp.ndarray) -> jnp.ndarray:
 
 def neighbor_min(ell: ELLGraph, t: jnp.ndarray) -> jnp.ndarray:
     """min_{w in N[v]} t[w] (closed: self-padding makes min include self)."""
-    return jnp.min(t[ell.neighbors], axis=1)
+    return jnp.min(xla_ops.gather_rows(t, ell.neighbors), axis=1)
 
 
 def neighbor_all_eq(ell: ELLGraph, m: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     """forall w in N[v]: m[w] == t[v] (closed; padding contributes m[v])."""
-    return jnp.all(m[ell.neighbors] == t[:, None], axis=1)
+    return jnp.all(xla_ops.gather_rows(m, ell.neighbors) == t[:, None], axis=1)
 
 
 def neighbor_any_eq(ell: ELLGraph, m: jnp.ndarray, value) -> jnp.ndarray:
     """exists w in N[v]: m[w] == value (closed)."""
-    return jnp.any(m[ell.neighbors] == value, axis=1)
+    return jnp.any(xla_ops.gather_rows(m, ell.neighbors) == value, axis=1)
 
 
 # ---------------------------------------------------------------------------

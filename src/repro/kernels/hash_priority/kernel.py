@@ -16,6 +16,7 @@ from jax.experimental import pallas as pl
 
 from ...core.hashing import priorities_xorshift_star
 from ...core.tuples import pack
+from .._interpret import resolve_interpret
 
 BLOCK = 1024
 
@@ -29,7 +30,7 @@ def _hash_pack_kernel(it_ref, ids_ref, out_ref, *, b: int):
 
 @functools.partial(jax.jit, static_argnames=("b", "interpret", "block"))
 def hash_pack_pallas(iteration, vertex_ids: jnp.ndarray, b: int, *,
-                     interpret: bool = True, block: int = BLOCK) -> jnp.ndarray:
+                     interpret=None, block: int = BLOCK) -> jnp.ndarray:
     n = vertex_ids.shape[0]
     blk = min(block, n)
     grid = pl.cdiv(n, blk)
@@ -43,5 +44,5 @@ def hash_pack_pallas(iteration, vertex_ids: jnp.ndarray, b: int, *,
             out_specs=pl.BlockSpec((blk,), lambda i, *_: (i,)),
         ),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(iteration, jnp.uint32).reshape(1), vertex_ids)

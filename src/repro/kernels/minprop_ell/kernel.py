@@ -2,24 +2,30 @@
 
 The paper's SIMD optimization reads each vertex's adjacency row with a
 warp so loads coalesce.  The TPU analogue (DESIGN.md §3): rows live in
-ELL layout, a *block* of rows ``[BLOCK_ROWS, D]`` is one VMEM tile, and the
-neighbor-tuple gather + min-reduce runs on the VPU across lanes.  The
-paper's *worklist* optimization maps to block-granular work skipping: the
-live worklist length is scalar-prefetched into SMEM and grid blocks whose
-row range lies entirely past ``count`` exit via ``pl.when`` without touching
-VMEM/HBM — the TPU equivalent of launching fewer thread blocks.
+ELL layout and the closed-neighborhood min / forall / exists reductions
+run on the VPU.  The paper's *worklist* optimization maps to
+block-granular work skipping: the live worklist length is
+scalar-prefetched into SMEM and grid blocks whose slot range lies entirely
+past ``count`` skip their compute via ``pl.when`` — the TPU equivalent of
+launching fewer thread blocks.
 
-Tiling:
-* ``wl_neighbors [W, D]`` — blocked ``[BLOCK_ROWS, D]`` along the grid.
-* ``t / m / active [V]``  — resident as a single VMEM block (uint32; 4 MB at
-  V = 1M).  For V beyond VMEM, the banded variant would block T by the
-  graph bandwidth (RCM-ordered meshes have O(V^(2/3)) bands); the tests
-  exercise the resident variant, which is the paper's problem regime.
-* gathers ``t[idx]`` inside the kernel are 1-D VMEM vector gathers
-  (``jnp.take``), the Mosaic-supported form.
+Tiling — column-major ELL tiles:
+* every kernel input is a ``[D, W]`` tile stack: the ``D`` neighbor slots
+  of a worklist row run down the sublanes and the ``W`` worklist slots
+  run across the lanes, blocked ``[D, block]`` along the grid.  A
+  closed-neighborhood reduction is then a sublane reduction whose result
+  is already a lane-dense ``[1, block]`` row of the ``[1, W]`` output.
+* the neighbor gathers (``T[nbrs]``, ``M[nbrs]``, ``active[nbrs]``) run
+  as XLA gathers in the same jitted program, in front of the kernel:
+  Mosaic gathers only within one vreg, so a gather from a ``[V]`` state
+  vector cannot run in-kernel, and the ``[V]`` vectors never have to fit
+  VMEM (V = 1M keeps 4 MB per state vector in HBM).
+* Mosaic reduces signed integers only; :func:`_umin_rows` maps the
+  unsigned tuple order onto the signed order by flipping the sign bit.
 
 Validated with ``interpret=True`` on CPU against ref.py (bit-exact — all
-integer math).
+integer math) and compiled for a described TPU v5e in
+``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -36,102 +42,143 @@ from jax.experimental.pallas import tpu as pltpu
 # touching the core drivers (no import cycle: core imports kernels lazily)
 from ...core.hashing import PRIORITY_FNS
 from ...core.tuples import pack
+from ...xla_ops import slot_major_rows
+from .._interpret import resolve_interpret
 
 IN = np.uint32(0)
 OUT = np.uint32(0xFFFFFFFF)
+_SIGN = np.uint32(0x80000000)
 
+# worklist slots per grid step (the lane dim of a tile: a multiple of 128)
 BLOCK_ROWS = 256
-# The fused resident kernels run their whole grid inside a lax.while_loop,
+# The fused resident passes run their whole grid inside a lax.while_loop,
 # so per-grid-step overhead is paid every round; a larger block amortizes
-# it (and still fits VMEM: 512 rows x D neighbor ids x 4 B is ~16 KB at
-# D=8, beside the [V]-resident T/M vectors).
+# it (512 slots x D neighbor tuples x 4 B is ~16 KB per tile at D=8).
 FUSED_BLOCK_ROWS = 512
 
 
-def _refresh_columns_kernel(count_ref, nbrs_ref, t_ref, m_ref):
-    """One grid step: M[block] = poisoned closed-neighborhood min of T."""
+def _umin_rows(x):
+    """Unsigned min over the sublanes of a uint32 tile -> ``[1, B]``."""
+    key = jax.lax.bitcast_convert_type(x ^ _SIGN, jnp.int32)
+    mn = jnp.min(key, axis=0, keepdims=True)
+    return jax.lax.bitcast_convert_type(mn, jnp.uint32) ^ _SIGN
+
+
+def _any_rows(pred):
+    """Exists over the sublanes of a bool tile -> ``[1, B]`` bool."""
+    return jnp.max(pred.astype(jnp.int32), axis=0, keepdims=True) > 0
+
+
+def _refresh_kernel(count_ref, tn_ref, m_ref):
+    """One grid step: M[block] = poisoned closed-neighborhood min of T.
+
+    ``tn`` holds the neighbor tuples already gathered (and, on the fused
+    path, already §V-A-refreshed) for this block of worklist slots."""
     i = pl.program_id(0)
-    block = nbrs_ref.shape[0]
+    block = m_ref.shape[1]
 
     @pl.when(i * block < count_ref[0])          # §V-B: skip dead blocks
     def _():
-        nbrs = nbrs_ref[...]                    # [B, D] int32
-        t = t_ref[...]                          # [V] uint32 (VMEM-resident)
-        tn = jnp.take(t, nbrs.reshape(-1), axis=0).reshape(nbrs.shape)
-        mv = jnp.min(tn, axis=1)
-        mv = jnp.where(mv == IN, OUT, mv)
-        m_ref[...] = mv
+        mv = _umin_rows(tn_ref[...])
+        m_ref[...] = jnp.where(mv == IN, OUT, mv)
 
     @pl.when(i * block >= count_ref[0])
     def _():
-        m_ref[...] = jnp.full((block,), OUT, dtype=jnp.uint32)
+        m_ref[...] = jnp.full(m_ref.shape, OUT, dtype=jnp.uint32)
 
 
-def _decide_kernel(count_ref, nbrs_ref, trow_ref, m_ref, act_ref, out_ref):
-    """One grid step: decide IN/OUT for a block of worklist rows."""
+def _decide_kernel(count_ref, told_ref, tv_ref, mn_ref, an_ref, out_ref):
+    """One grid step: decide IN/OUT for a block of worklist slots.
+
+    ``told`` is the slot's stored tuple, ``tv`` the tuple the decision
+    compares against (the §V-A-refreshed one on the fused path), ``mn`` /
+    ``an`` the neighbors' M values and active flags."""
     i = pl.program_id(0)
-    block = nbrs_ref.shape[0]
+    block = out_ref.shape[1]
 
     @pl.when(i * block < count_ref[0])
     def _():
-        nbrs = nbrs_ref[...]                    # [B, D]
-        tv = trow_ref[...]                      # [B]
-        m = m_ref[...]                          # [V]
-        act = act_ref[...]                      # [V]
-        flat = nbrs.reshape(-1)
-        mn = jnp.take(m, flat, axis=0).reshape(nbrs.shape)
-        an = jnp.take(act, flat, axis=0).reshape(nbrs.shape)
-        any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
-        all_eq = jnp.all(jnp.where(an, mn, tv[:, None]) == tv[:, None], axis=1)
-        newt = jnp.where(any_out, OUT, jnp.where(all_eq, IN, tv))
-        und = (tv != IN) & (tv != OUT)
-        out_ref[...] = jnp.where(und, newt, tv)
+        tv_old = told_ref[...]                  # [1, B]
+        tv = tv_ref[...]                        # [1, B]
+        mn = mn_ref[...]                        # [D, B]
+        an = an_ref[...] != 0                   # [D, B]
+        any_out = _any_rows(an & (mn == OUT))
+        any_neq = _any_rows(an & (mn != tv))
+        newt = jnp.where(any_out, OUT, jnp.where(any_neq, tv, IN))
+        und = (tv_old != IN) & (tv_old != OUT)
+        out_ref[...] = jnp.where(und, newt, tv_old)
 
     @pl.when(i * block >= count_ref[0])
     def _():
-        out_ref[...] = trow_ref[...]
+        out_ref[...] = told_ref[...]
+
+
+def _tiled_call(kernel, count, tiles, *, block_rows: int, interpret: bool):
+    """Run ``kernel`` over ``[D_k, W]`` tiles blocked ``[D_k, block]``
+    along the worklist slots; returns the ``[W]`` uint32 output row."""
+    w = tiles[0].shape[1]
+    block = w if w <= block_rows else block_rows
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(w, block),),
+            in_specs=[pl.BlockSpec((x.shape[0], block), lambda i, *_: (0, i))
+                      for x in tiles],
+            out_specs=pl.BlockSpec((1, block), lambda i, *_: (0, i)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, w), jnp.uint32),
+        interpret=interpret,
+    )(jnp.asarray(count, jnp.int32).reshape(1), *tiles)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def refresh_columns_pallas(t: jnp.ndarray, wl_neighbors: jnp.ndarray,
-                           count: jnp.ndarray, *, interpret: bool = True,
+                           count: jnp.ndarray, *, interpret=None,
                            block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
     """M values for the (padded) worklist rows. Rows >= count return OUT."""
-    w, d = wl_neighbors.shape
-    block = min(block_rows, w)
-    grid = pl.cdiv(w, block)
-    v = t.shape[0]
-    return pl.pallas_call(
-        _refresh_columns_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block, d), lambda i, *_: (i, 0)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i, *_: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
-        interpret=interpret,
-    )(count.reshape(1), wl_neighbors, t)
+    tn = t[wl_neighbors.T]                      # [D, W]
+    return _tiled_call(_refresh_kernel, count, [tn], block_rows=block_rows,
+                       interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
+def decide_pallas(t_rows: jnp.ndarray, m: jnp.ndarray, active: jnp.ndarray,
+                  wl_neighbors: jnp.ndarray, count: jnp.ndarray, *,
+                  interpret=None,
+                  block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
+    """New T values for the (padded) worklist rows; rows >= count keep
+    their ``t_rows`` value."""
+    nb = wl_neighbors.T                         # [D, W]
+    trow = t_rows[None, :]
+    return _tiled_call(_decide_kernel, count,
+                       [trow, trow, m[nb], active[nb].astype(jnp.int32)],
+                       block_rows=block_rows,
+                       interpret=resolve_interpret(interpret))
 
 
 # ===========================================================================
-# fused passes for the device-resident engine (``pallas_resident``)
+# fused passes for the device-resident engines (``pallas_resident``,
+# ``pallas_hybrid``)
 #
-# The host-driven round moves each live row's ELL entries through HBM three
-# times: the XLA ``_gather_rows`` reads the row and writes a ``[W, D]``
-# worklist copy, then the kernel reads that copy back — per pass.  The
-# fused kernels below take the worklist *indices* plus the flat ``[V*D]``
-# adjacency and do the row gather in-kernel (one read, no materialized
-# copy).  The §V-A rank packing is folded into the same gather: an
-# undecided tuple's refreshed value is a pure function of (vertex id,
-# round), so instead of a separate refresh_rows scatter pass the kernels
-# recompute it on the fly for every gathered neighbor.  The stored T is
-# only written once per round, by the decide scatter — and because decide
-# writes the refreshed tuple for still-undecided rows, the stored state
-# after each round is bit-identical to the three-pass host pipeline.
+# The host-driven round materializes each live row's ELL entries as a
+# ``[W, D]`` worklist copy in its own dispatch, then gathers T through the
+# copy.  The fused wrappers take the worklist *indices* plus the
+# adjacency, so the row gather, the T/M gathers and the kernel trace into
+# one program with the resident while_loop.  The §V-A rank packing is
+# folded into the same gather: an undecided tuple's refreshed value is a
+# pure function of (vertex id, round), so instead of a separate
+# refresh_rows scatter pass the gather recomputes it on the fly for every
+# gathered neighbor.  The stored T is only written once per round, by the
+# decide scatter — and because decide writes the refreshed tuple for
+# still-undecided rows, the stored state after each round is bit-identical
+# to the three-pass host pipeline.
+#
+# One wrapper pair serves both layouts: ``neighbors`` is either the
+# monolithic ``[V, D]`` ELL (worklist slots index global rows) or one
+# degree-bucket slab ``[R, W]`` of the hybrid layout (slots index slab
+# rows and ``gids`` carries the matching global ids, because the refresh
+# packing and the T read are keyed by global id).
 # ===========================================================================
 
 def _refresh_inline(t_vals, ids, it, priority: str, b: int):
@@ -146,214 +193,50 @@ def _refresh_inline(t_vals, ids, it, priority: str, b: int):
     return jnp.where(und, fresh, t_vals)
 
 
-def _gather_rows_inkernel(nbrs_flat, rows, d: int):
-    """[B] row ids -> [B, d] neighbor ids via a 1-D VMEM vector gather."""
-    block = rows.shape[0]
-    idx = rows[:, None] * d + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (block, d), 1)
-    return jnp.take(nbrs_flat, idx.reshape(-1), axis=0).reshape(block, d)
-
-
-def _fused_refresh_columns_kernel(count_ref, it_ref, wl_ref, nbrs_ref,
-                                  t_ref, m_ref, *, priority: str, b: int,
-                                  d: int):
-    """One grid step: M[wl block] from ONE in-kernel read of the ELL rows,
-    with the §V-A rank packing applied to the gathered tuples on the fly."""
-    i = pl.program_id(0)
-    block = wl_ref.shape[0]
-
-    @pl.when(i * block < count_ref[0])          # §V-B: skip dead blocks
-    def _():
-        v = t_ref.shape[0]
-        rows = jnp.clip(wl_ref[...], 0, v - 1)  # sentinel slots: dropped later
-        nbrs = _gather_rows_inkernel(nbrs_ref[...], rows, d)
-        t = t_ref[...]
-        tn = jnp.take(t, nbrs.reshape(-1), axis=0).reshape(nbrs.shape)
-        tn = _refresh_inline(tn, nbrs.astype(jnp.uint32), it_ref[0],
-                             priority, b)
-        mv = jnp.min(tn, axis=1)
-        m_ref[...] = jnp.where(mv == IN, OUT, mv)
-
-    @pl.when(i * block >= count_ref[0])
-    def _():
-        m_ref[...] = jnp.full((block,), OUT, dtype=jnp.uint32)
-
-
-def _fused_decide_kernel(count_ref, it_ref, wl_ref, nbrs_ref, t_ref, m_ref,
-                         act_ref, out_ref, *, priority: str, b: int, d: int):
-    """One grid step: IN/OUT decision for a block of worklist rows, with
-    the row tuple gather + refresh folded in (no pre-gathered T rows)."""
-    i = pl.program_id(0)
-    block = wl_ref.shape[0]
-
-    @pl.when(i * block < count_ref[0])
-    def _():
-        v = t_ref.shape[0]
-        rows = jnp.clip(wl_ref[...], 0, v - 1)
-        t = t_ref[...]
-        tv_old = jnp.take(t, rows, axis=0)
-        tv = _refresh_inline(tv_old, rows.astype(jnp.uint32), it_ref[0],
-                             priority, b)
-        nbrs = _gather_rows_inkernel(nbrs_ref[...], rows, d)
-        flat = nbrs.reshape(-1)
-        mn = jnp.take(m_ref[...], flat, axis=0).reshape(nbrs.shape)
-        an = jnp.take(act_ref[...], flat, axis=0).reshape(nbrs.shape)
-        any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
-        all_eq = jnp.all(jnp.where(an, mn, tv[:, None]) == tv[:, None], axis=1)
-        newt = jnp.where(any_out, OUT, jnp.where(all_eq, IN, tv))
-        und = (tv_old != IN) & (tv_old != OUT)
-        out_ref[...] = jnp.where(und, newt, tv_old)
-
-    @pl.when(i * block >= count_ref[0])
-    def _():
-        # every slot of a dead block holds the sentinel V: the scatter back
-        # into T drops all of them, so the fill value is never observed
-        out_ref[...] = jnp.zeros((block,), dtype=jnp.uint32)
-
-
 @functools.partial(jax.jit, static_argnames=("priority", "b", "interpret",
                                              "block_rows"))
-def fused_refresh_columns_pallas(t: jnp.ndarray, nbrs_flat: jnp.ndarray,
+def fused_refresh_columns_pallas(t: jnp.ndarray, neighbors: jnp.ndarray,
                                  wl: jnp.ndarray, count: jnp.ndarray,
                                  it: jnp.ndarray, *, priority: str, b: int,
-                                 interpret: bool = True,
+                                 interpret=None,
                                  block_rows: int = FUSED_BLOCK_ROWS) -> jnp.ndarray:
-    """Fused refresh_rows+refresh_columns: M values for the worklist slots.
+    """Fused refresh_rows+refresh_columns: M values per worklist slot.
 
-    ``wl`` is a full ``[V]`` sentinel-padded index buffer (the resident
-    driver's fixed-shape worklist); ``count`` may be traced — it reaches
-    the kernel via scalar prefetch, so block skipping follows the *live*
-    worklist length with no host involvement.
+    ``wl`` is a sentinel-padded index buffer over the rows of
+    ``neighbors`` (the resident drivers' fixed-shape worklist); ``count``
+    may be traced — it reaches the kernel via scalar prefetch, so block
+    skipping follows the *live* worklist length with no host involvement.
+    ``t`` is always the global ``[V]`` state.
     """
-    v = t.shape[0]
-    w = wl.shape[0]
-    d = nbrs_flat.shape[0] // v
-    block = min(block_rows, w)
-    grid = pl.cdiv(w, block)
-    kernel = functools.partial(_fused_refresh_columns_kernel,
-                               priority=priority, b=b, d=d)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i, *_: (i,)),
-                pl.BlockSpec((v * d,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i, *_: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
-        interpret=interpret,
-    )(count.reshape(1), it.reshape(1), wl, nbrs_flat, t)
+    nb = slot_major_rows(neighbors, wl)
+    tn = _refresh_inline(t[nb], nb.astype(jnp.uint32),
+                         jnp.asarray(it, jnp.uint32), priority, b)
+    return _tiled_call(_refresh_kernel, count, [tn], block_rows=block_rows,
+                       interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("priority", "b", "interpret",
                                              "block_rows"))
 def fused_decide_pallas(t: jnp.ndarray, m: jnp.ndarray, active: jnp.ndarray,
-                        nbrs_flat: jnp.ndarray, wl: jnp.ndarray,
-                        count: jnp.ndarray, it: jnp.ndarray, *,
-                        priority: str, b: int, interpret: bool = True,
+                        neighbors: jnp.ndarray, wl: jnp.ndarray,
+                        count: jnp.ndarray, it: jnp.ndarray, gids=None, *,
+                        priority: str, b: int, interpret=None,
                         block_rows: int = FUSED_BLOCK_ROWS) -> jnp.ndarray:
-    """Fused row-gather+decide: new T values for the worklist slots."""
+    """Fused row-gather+decide: new T values per worklist slot.
+
+    ``gids`` maps each slot to its global row when ``neighbors`` is a
+    slab (``None``: the slots index global rows); the driver scatters the
+    output back into T at those ids with drop semantics."""
     v = t.shape[0]
-    w = wl.shape[0]
-    d = nbrs_flat.shape[0] // v
-    block = min(block_rows, w)
-    grid = pl.cdiv(w, block)
-    kernel = functools.partial(_fused_decide_kernel, priority=priority,
-                               b=b, d=d)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i, *_: (i,)),
-                pl.BlockSpec((v * d,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i, *_: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
-        interpret=interpret,
-    )(count.reshape(1), it.reshape(1), wl, nbrs_flat, t, m, active)
-
-
-# ===========================================================================
-# sliced passes for the hybrid layout (``pallas_hybrid``)
-#
-# Same fused shape as above, but the adjacency block is one degree-bucket
-# slab ``[R_i, W_i]`` instead of the monolithic ``[V, max_degree]`` ELL:
-# the worklist indices are *slice-local* (sentinel R_i), the slab gather
-# resolves them locally, and only the T/M/active state vectors stay
-# global ``[V]``.  The decide kernel additionally receives the worklist
-# slots' *global* row ids (precomputed by the driver from ``slice.rows``)
-# because the refresh packing and the T read are keyed by global id.
-# One pallas_call per slice per pass — compile count O(#slices).
-# ===========================================================================
-
-def _sliced_refresh_columns_kernel(count_ref, it_ref, wl_ref, nbrs_ref,
-                                   t_ref, m_ref, *, priority: str, b: int,
-                                   d: int):
-    """One grid step: M[wl block] for one slice slab.  ``wl`` holds
-    slice-local row positions; the gathered neighbor ids are global."""
-    i = pl.program_id(0)
-    block = wl_ref.shape[0]
-
-    @pl.when(i * block < count_ref[0])          # §V-B: skip dead blocks
-    def _():
-        r = nbrs_ref.shape[0] // d              # rows in THIS slab
-        rows = jnp.clip(wl_ref[...], 0, r - 1)  # sentinel slots: dropped later
-        nbrs = _gather_rows_inkernel(nbrs_ref[...], rows, d)
-        t = t_ref[...]
-        tn = jnp.take(t, nbrs.reshape(-1), axis=0).reshape(nbrs.shape)
-        tn = _refresh_inline(tn, nbrs.astype(jnp.uint32), it_ref[0],
-                             priority, b)
-        mv = jnp.min(tn, axis=1)
-        m_ref[...] = jnp.where(mv == IN, OUT, mv)
-
-    @pl.when(i * block >= count_ref[0])
-    def _():
-        # dead-block slots scatter to the sentinel target and are dropped
-        m_ref[...] = jnp.full((block,), OUT, dtype=jnp.uint32)
-
-
-def _sliced_decide_kernel(count_ref, it_ref, wl_ref, gid_ref, nbrs_ref,
-                          t_ref, m_ref, act_ref, out_ref, *, priority: str,
-                          b: int, d: int):
-    """One grid step: IN/OUT decision for a block of one slice's worklist.
-    ``wl`` indexes the slab; ``gid`` carries the matching global ids."""
-    i = pl.program_id(0)
-    block = wl_ref.shape[0]
-
-    @pl.when(i * block < count_ref[0])
-    def _():
-        r = nbrs_ref.shape[0] // d
-        v = t_ref.shape[0]
-        rows = jnp.clip(wl_ref[...], 0, r - 1)
-        gids = jnp.clip(gid_ref[...], 0, v - 1)
-        t = t_ref[...]
-        tv_old = jnp.take(t, gids, axis=0)
-        tv = _refresh_inline(tv_old, gids.astype(jnp.uint32), it_ref[0],
-                             priority, b)
-        nbrs = _gather_rows_inkernel(nbrs_ref[...], rows, d)
-        flat = nbrs.reshape(-1)
-        mn = jnp.take(m_ref[...], flat, axis=0).reshape(nbrs.shape)
-        an = jnp.take(act_ref[...], flat, axis=0).reshape(nbrs.shape)
-        any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
-        all_eq = jnp.all(jnp.where(an, mn, tv[:, None]) == tv[:, None], axis=1)
-        newt = jnp.where(any_out, OUT, jnp.where(all_eq, IN, tv))
-        und = (tv_old != IN) & (tv_old != OUT)
-        out_ref[...] = jnp.where(und, newt, tv_old)
-
-    @pl.when(i * block >= count_ref[0])
-    def _():
-        # dead-block slots scatter to the sentinel target and are dropped
-        out_ref[...] = jnp.zeros((block,), dtype=jnp.uint32)
+    g = jnp.clip(wl if gids is None else gids, 0, v - 1)
+    tv_old = t[g]
+    tv = _refresh_inline(tv_old, g.astype(jnp.uint32),
+                         jnp.asarray(it, jnp.uint32), priority, b)
+    nb = slot_major_rows(neighbors, wl)
+    return _tiled_call(
+        _decide_kernel, count,
+        [tv_old[None, :], tv[None, :], m[nb], active[nb].astype(jnp.int32)],
+        block_rows=block_rows, interpret=resolve_interpret(interpret))
 
 
 # VMEM budget per worklist block in slab entries (rows x width); the block
@@ -364,105 +247,9 @@ SLICE_BLOCK_ENTRIES = FUSED_BLOCK_ROWS * 8
 def slice_block_rows(num_rows: int, width: int, interpret: bool) -> int:
     """Worklist block size for one slice.  Interpret mode executes grid
     steps as a sequential host scan, so it takes the whole slice as one
-    block; compiled mode tiles to ~SLICE_BLOCK_ENTRIES slab entries."""
+    block; compiled mode tiles to ~SLICE_BLOCK_ENTRIES slab entries, in
+    whole 128-lane columns."""
     if interpret:
         return max(1, num_rows)
-    return max(8, min(num_rows, SLICE_BLOCK_ENTRIES // max(width, 1)))
-
-
-@functools.partial(jax.jit, static_argnames=("priority", "b", "d",
-                                             "interpret", "block_rows"))
-def sliced_refresh_columns_pallas(t: jnp.ndarray, nbrs_flat: jnp.ndarray,
-                                  wl: jnp.ndarray, count: jnp.ndarray,
-                                  it: jnp.ndarray, *, priority: str, b: int,
-                                  d: int, interpret: bool = True,
-                                  block_rows: int = FUSED_BLOCK_ROWS) -> jnp.ndarray:
-    """Fused refresh for one slice: M values per slice-local worklist slot.
-
-    ``nbrs_flat`` is the slab ``[R*d]``; ``wl`` is ``[R]`` sentinel-padded
-    with slice-local positions; ``t`` stays the global ``[V]`` state."""
-    v = t.shape[0]
-    r = nbrs_flat.shape[0] // d
-    block = min(block_rows, max(r, 1))
-    grid = pl.cdiv(r, block)
-    kernel = functools.partial(_sliced_refresh_columns_kernel,
-                               priority=priority, b=b, d=d)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i, *_: (i,)),
-                pl.BlockSpec((r * d,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i, *_: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((r,), jnp.uint32),
-        interpret=interpret,
-    )(count.reshape(1), it.reshape(1), wl, nbrs_flat, t)
-
-
-@functools.partial(jax.jit, static_argnames=("priority", "b", "d",
-                                             "interpret", "block_rows"))
-def sliced_decide_pallas(t: jnp.ndarray, m: jnp.ndarray, active: jnp.ndarray,
-                         nbrs_flat: jnp.ndarray, wl: jnp.ndarray,
-                         gids: jnp.ndarray, count: jnp.ndarray,
-                         it: jnp.ndarray, *, priority: str, b: int, d: int,
-                         interpret: bool = True,
-                         block_rows: int = FUSED_BLOCK_ROWS) -> jnp.ndarray:
-    """Fused decide for one slice: new T values per slice-local worklist
-    slot (``gids`` maps each slot to its global row; the driver scatters
-    the output back into T at those ids with drop semantics)."""
-    v = t.shape[0]
-    r = nbrs_flat.shape[0] // d
-    block = min(block_rows, max(r, 1))
-    grid = pl.cdiv(r, block)
-    kernel = functools.partial(_sliced_decide_kernel, priority=priority,
-                               b=b, d=d)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i, *_: (i,)),
-                pl.BlockSpec((block,), lambda i, *_: (i,)),
-                pl.BlockSpec((r * d,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i, *_: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((r,), jnp.uint32),
-        interpret=interpret,
-    )(count.reshape(1), it.reshape(1), wl, gids, nbrs_flat, t, m, active)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def decide_pallas(t_rows: jnp.ndarray, m: jnp.ndarray, active: jnp.ndarray,
-                  wl_neighbors: jnp.ndarray, count: jnp.ndarray, *,
-                  interpret: bool = True,
-                  block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
-    w, d = wl_neighbors.shape
-    block = min(block_rows, w)
-    grid = pl.cdiv(w, block)
-    v = m.shape[0]
-    return pl.pallas_call(
-        _decide_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block, d), lambda i, *_: (i, 0)),
-                pl.BlockSpec((block,), lambda i, *_: (i,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-                pl.BlockSpec((v,), lambda i, *_: (0,)),
-            ],
-            out_specs=pl.BlockSpec((block,), lambda i, *_: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
-        interpret=interpret,
-    )(count.reshape(1), wl_neighbors, t_rows, m, active)
+    lanes = SLICE_BLOCK_ENTRIES // max(width, 1) // 128 * 128
+    return max(128, lanes)

@@ -1,9 +1,11 @@
 """Jitted wrappers wiring the Pallas min-propagation kernels into the
 compacted MIS-2 driver (core/mis2.py, engine ``"pallas"``).
 
-The XLA side does the irregular parts (worklist row gather, scatter-back);
-the Pallas kernels fuse the neighbor-tuple gather + reductions, which is
-the paper's measured hot loop.
+The XLA side does the irregular parts (worklist row gather, the
+neighbor-tuple gathers, scatter-back); the Pallas kernels do the
+closed-neighborhood reductions and the IN/OUT decision over the gathered
+``[D, W]`` tiles (see ``kernel.py`` for why the gathers cannot run
+in-kernel on the TPU).
 
 ``interpret=None`` (the default) defers to the :class:`repro.api.Backend`
 policy: interpret only when no accelerator is attached.  The seed
@@ -24,8 +26,6 @@ from .kernel import (
     fused_refresh_columns_pallas,
     refresh_columns_pallas,
     slice_block_rows,
-    sliced_decide_pallas,
-    sliced_refresh_columns_pallas,
 )
 
 IN = np.uint32(0)
@@ -34,17 +34,23 @@ OUT = np.uint32(0xFFFFFFFF)
 # ---------------------------------------------------------------------------
 # ELL row-traffic model (asserted by tests/test_resident.py)
 #
-# HBM movements of one live worklist row's ELL entries per per-round pass:
-# the host-driven path gathers the row in XLA (1 read), materializes the
-# [W, D] worklist copy (1 write), and the kernel reads the copy back
-# (1 read) — 3 movements.  The fused resident kernels gather the row
-# in-kernel from the flat [V*D] adjacency: 1 read, no copy.
+# HBM movements of one live worklist row's ELL entries (its neighbor ids)
+# per per-round pass, as the algorithm schedules them: the host-driven
+# path gathers the row in its own dispatch (1 read), materializes the
+# [W, D] worklist copy (1 write), and the gather behind the kernel reads
+# the copy back (1 read) — 3 movements.  The fused resident wrappers
+# gather the ids inside the same program as the T/M gathers, with no
+# worklist copy crossing a dispatch: 1 read.  Not modeled: the gathered
+# tuple tiles the kernels reduce ([D, W] T values, M values, active
+# flags), which the XLA gathers write and the kernels read on every path,
+# and whatever intermediates XLA's gather lowering keeps (device traffic
+# not measured).
 # ---------------------------------------------------------------------------
 
 ELL_ROW_TRAFFIC = {
     "pallas": {"reads": 2, "writes": 1},
     "pallas_resident": {"reads": 1, "writes": 0},
-    # hybrid slices reuse the fused in-kernel gather (1 read of W_i ids per
+    # hybrid slices reuse the fused gather (1 read of W_i ids per
     # live worklist row per pass, no materialized copy); the spill segment
     # is COO, accounted per entry, not per padded row
     "pallas_hybrid": {"reads": 1, "writes": 0},
@@ -114,7 +120,7 @@ def fused_refresh_columns(t, m, wl2, count, neighbors, it, *, priority: str,
     """M.at[wl2] <- poisoned min over wl2 rows' closed neighborhoods, with
     the §V-A row refresh applied to the gathered tuples on the fly."""
     mv = fused_refresh_columns_pallas(
-        t, neighbors.reshape(-1), wl2, jnp.asarray(count, jnp.int32),
+        t, neighbors, wl2, jnp.asarray(count, jnp.int32),
         jnp.asarray(it, jnp.uint32), priority=priority, b=b,
         interpret=_resolve_interpret(interpret))
     return m.at[wl2].set(mv, mode="drop")
@@ -122,24 +128,24 @@ def fused_refresh_columns(t, m, wl2, count, neighbors, it, *, priority: str,
 
 def fused_decide(t, m, wl1, count, neighbors, active, it, *, priority: str,
                  b: int, interpret=None):
-    """T.at[wl1] <- IN/OUT decision, row tuple gather + refresh in-kernel.
+    """T.at[wl1] <- IN/OUT decision, row tuple gather + refresh fused.
 
     Because still-undecided rows get their *refreshed* tuple written back,
     this single scatter leaves T exactly as the host pipeline's
     refresh_rows + decide pair would."""
     newt = fused_decide_pallas(
-        t, m, active, neighbors.reshape(-1), wl1,
+        t, m, active, neighbors, wl1,
         jnp.asarray(count, jnp.int32), jnp.asarray(it, jnp.uint32),
         priority=priority, b=b, interpret=_resolve_interpret(interpret))
     return t.at[wl1].set(newt, mode="drop")
 
 
 # ---------------------------------------------------------------------------
-# hybrid-layout passes (``pallas_hybrid``): per-slice fused kernels over the
-# sliced-ELL slabs + XLA segment reductions over the sorted-COO spill.  All
-# of these trace inside the hybrid resident while_loop; the slice worklists
-# are slice-local (sentinel R_i) and every write back into the global [V]
-# state goes through a global-id scatter with drop semantics.
+# hybrid-layout passes (``pallas_hybrid``): the fused passes per slice over
+# the sliced-ELL slabs + XLA segment reductions over the sorted-COO spill.
+# All of these trace inside the hybrid resident while_loop; the slice
+# worklists are slice-local (sentinel R_i) and every write back into the
+# global [V] state goes through a global-id scatter with drop semantics.
 # ---------------------------------------------------------------------------
 
 def _slice_gids(slice_rows, wl, v: int):
@@ -150,34 +156,33 @@ def _slice_gids(slice_rows, wl, v: int):
                      jnp.int32(v))
 
 
-def sliced_refresh_columns(t, m, slice_rows, nbrs_flat, wl, count, it, *,
-                           priority: str, b: int, d: int, interpret=None,
+def sliced_refresh_columns(t, m, slice_rows, neighbors, wl, count, it, *,
+                           priority: str, b: int, interpret=None,
                            block_rows=None):
     """M.at[slice rows on the worklist] <- poisoned closed-neighborhood min
     (the fused refresh, restricted to one degree-bucket slab)."""
     interp = _resolve_interpret(interpret)
     if block_rows is None:
-        block_rows = slice_block_rows(slice_rows.shape[0], d, interp)
-    mv = sliced_refresh_columns_pallas(
-        t, nbrs_flat, wl, jnp.asarray(count, jnp.int32),
-        jnp.asarray(it, jnp.uint32), priority=priority, b=b, d=d,
+        block_rows = slice_block_rows(*neighbors.shape, interp)
+    mv = fused_refresh_columns_pallas(
+        t, neighbors, wl, jnp.asarray(count, jnp.int32),
+        jnp.asarray(it, jnp.uint32), priority=priority, b=b,
         interpret=interp, block_rows=block_rows)
     gids = _slice_gids(slice_rows, wl, t.shape[0])
     return m.at[gids].set(mv, mode="drop")
 
 
-def sliced_decide(t, m, active, slice_rows, nbrs_flat, wl, count, it, *,
-                  priority: str, b: int, d: int, interpret=None,
-                  block_rows=None):
+def sliced_decide(t, m, active, slice_rows, neighbors, wl, count, it, *,
+                  priority: str, b: int, interpret=None, block_rows=None):
     """T.at[slice rows on the worklist] <- IN/OUT decision (fused decide,
     restricted to one slab; global row ids ride alongside the worklist)."""
     interp = _resolve_interpret(interpret)
     if block_rows is None:
-        block_rows = slice_block_rows(slice_rows.shape[0], d, interp)
+        block_rows = slice_block_rows(*neighbors.shape, interp)
     gids = _slice_gids(slice_rows, wl, t.shape[0])
-    newt = sliced_decide_pallas(
-        t, m, active, nbrs_flat, wl, gids, jnp.asarray(count, jnp.int32),
-        jnp.asarray(it, jnp.uint32), priority=priority, b=b, d=d,
+    newt = fused_decide_pallas(
+        t, m, active, neighbors, wl, jnp.asarray(count, jnp.int32),
+        jnp.asarray(it, jnp.uint32), gids, priority=priority, b=b,
         interpret=interp, block_rows=block_rows)
     return t.at[gids].set(newt, mode="drop")
 

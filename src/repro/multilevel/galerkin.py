@@ -22,7 +22,7 @@ The host backend mirrors the device backend primitive-for-primitive
 (``np.argsort(kind='stable')``/``np.add.at`` against jnp stable argsort/
 ``segment_sum`` — both accumulate in order on CPU, asserted by the
 digest-parity gate).  All arithmetic is float64 (the device backend runs
-under ``jax.experimental.enable_x64``); the float32 results agree with
+under ``jax.enable_x64``); the float32 results agree with
 the legacy scipy path ``graphs.ops.galerkin_coarse_matrix`` to rounding
 (property-tested in ``tests/test_multilevel.py``) and agree across the
 two backends bitwise.
@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from ..graphs.csr import CSRMatrix, ELLMatrix
 
 
@@ -49,8 +50,8 @@ def _kept_row_slots(rows, keep, num_rows: int):
     the slot arithmetic cannot drift between them."""
     counts = jnp.zeros(num_rows + 1, jnp.int32).at[
         jnp.where(keep, rows, num_rows)].add(1)[:-1]
-    starts = jnp.cumsum(counts) - counts
-    rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
+    starts = xla_ops.cumsum(counts) - counts
+    rank = xla_ops.cumsum(keep.astype(jnp.int32)) - 1
     slot = rank - starts[jnp.clip(rows, 0, num_rows - 1)]
     return jnp.where(keep, rows, num_rows), slot
 
@@ -60,11 +61,11 @@ def _run_sums_device(keys, vals):
     exact-zero totals.  Returns ``(keys_sorted, sums, keep)`` with
     ``sums`` replicated over each run and ``keep`` marking nonzero run
     heads."""
-    order = jnp.argsort(keys, stable=True)
+    order = xla_ops.argsort_int64(keys)
     keys = keys[order]
     vals = vals[order]
     head = jnp.concatenate([jnp.ones(1, bool), keys[1:] != keys[:-1]])
-    rid = jnp.cumsum(head.astype(jnp.int32)) - 1
+    rid = xla_ops.cumsum(head.astype(jnp.int32)) - 1
     totals = jax.ops.segment_sum(vals, rid, num_segments=vals.shape[0])
     sums = totals[rid]
     keep = head & (sums != 0.0)
@@ -81,10 +82,10 @@ def _spgemm_stage1_device(a_cols, a_vals64, p_cols, p_vals64, *,
     v, d = a_cols.shape
     dp = p_cols.shape[1]
     w = a_cols                                               # [V, D]
-    vals = (a_vals64[:, :, None] * p_vals64[w]).reshape(-1)
+    vals = (a_vals64[:, :, None] * xla_ops.gather_rows(p_vals64, w)).reshape(-1)
     vids = jnp.arange(v, dtype=jnp.int64)
     keys = (vids[:, None, None] * key_base
-            + p_cols.astype(jnp.int64)[w]
+            + xla_ops.gather_rows(p_cols.astype(jnp.int64), w)
             + jnp.zeros((v, d, dp), dtype=jnp.int64)).reshape(-1)
     keys, sums, keep = _run_sums_device(keys, vals)
     rows = (keys // key_base).astype(jnp.int32)
@@ -178,14 +179,14 @@ def _spgemm_stage1_dense_device(a_cols, a_vals64, p_cols, p_vals64, *,
     scalars the extraction dispatch needs."""
     v, d = a_cols.shape
     w = a_cols
-    vals = (a_vals64[:, :, None] * p_vals64[w]).reshape(-1)
+    vals = (a_vals64[:, :, None] * xla_ops.gather_rows(p_vals64, w)).reshape(-1)
     vids = jnp.arange(v, dtype=jnp.int32)
-    idx = (vids[:, None, None] * num_cols + p_cols[w]
+    idx = (vids[:, None, None] * num_cols + xla_ops.gather_rows(p_cols, w)
            + jnp.zeros(a_vals64.shape + (p_cols.shape[1],),
                        dtype=jnp.int32)).reshape(-1)
     dense = jnp.zeros(v * num_cols, jnp.float64).at[idx].add(vals)
     mask = dense != 0.0
-    csum = jnp.cumsum(mask.astype(jnp.int32))
+    csum = xla_ops.cumsum(mask.astype(jnp.int32))
     row_nnz = csum.reshape(v, num_cols)[:, -1]
     row_nnz = jnp.diff(row_nnz, prepend=jnp.int32(0))
     return dense, csum, jnp.max(row_nnz), csum[-1]
@@ -236,7 +237,7 @@ def _spgemm_stage2_dense_device(p_cols, p_vals64, q_cols, q_vals64, *,
            + jnp.zeros((v, dp, dq), dtype=jnp.int32)).reshape(-1)
     dense = jnp.zeros(num_cols * num_cols, jnp.float64).at[idx].add(vals)
     mask = dense != 0.0
-    csum = jnp.cumsum(mask.astype(jnp.int32))
+    csum = xla_ops.cumsum(mask.astype(jnp.int32))
     row_nnz = csum.reshape(num_cols, num_cols)[:, -1]
     row_nnz = jnp.diff(row_nnz, prepend=jnp.int32(0))
     return dense, csum, jnp.max(row_nnz), csum[-1]
@@ -403,7 +404,7 @@ def _coarse_graph_keys_device(neighbors, mask, labels, *, key_base: int):
     """Unique sorted coarse-edge keys ``la * K + lb`` (+ the diagonal),
     the device counterpart of ``graphs.ops.coarse_graph_from_labels``."""
     la = labels.astype(jnp.int64)
-    lb = labels[neighbors].astype(jnp.int64)
+    lb = xla_ops.gather_rows(labels, neighbors).astype(jnp.int64)
     keys = jnp.where(mask, la[:, None] * key_base + lb, jnp.int64(-1))
     diag = la * key_base + la
     keys = jnp.concatenate([keys.reshape(-1), diag])

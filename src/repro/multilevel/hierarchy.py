@@ -10,7 +10,7 @@ colors — the PR-3/PR-4 bit-identity discipline):
   level round-trips matrix-sized data through host memory (counted in
   ``SETUP_STATS.host_syncs``).
 * ``resident``  the whole per-level setup runs jitted on device under
-  ``jax.experimental.enable_x64``: prolongator assembly from aggregation
+  ``jax.enable_x64``: prolongator assembly from aggregation
   labels via fixed-shape sort/segment-sum, the Galerkin triple product as
   a padded sorted-COO SpGEMM, coarse-level ELL repacking, and cluster/
   color row packing — reusing the PR-4 resident aggregation and coloring
@@ -69,9 +69,7 @@ def x64_context():
     """Float64 tracing scope for the resident setup path (the host scipy
     reference computes in f64; the device path must match it before the
     final float32 rounding)."""
-    from jax.experimental import enable_x64
-
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
+
 
 # ---------------------------------------------------------------------------
 # host backend (numpy; the reference)
@@ -84,7 +86,7 @@ def _pack_rows_device(row_order, labels, *, num_clusters: int, max_len: int):
     v = labels.shape[0]
     lab_s = labels[row_order]
     head = jnp.concatenate([jnp.ones(1, bool), lab_s[1:] != lab_s[:-1]])
-    crow = jnp.cumsum(head.astype(jnp.int32)) - 1       # cluster rank
+    crow = xla_ops.cumsum(head.astype(jnp.int32)) - 1       # cluster rank
     pos = jnp.arange(v, dtype=jnp.int32)
     starts = jnp.where(head, pos, 0)
     starts = jax.lax.cummax(starts)                     # start of own cluster
@@ -98,11 +100,9 @@ def pack_clusters_device(labels, cluster_colors, num_colors: int, v: int):
     """Device packing; returns the same per-color tuple as the host
     backend, as slices of one device-resident block (no host copy of the
     packed rows — only the per-color geometry scalars come back)."""
-    from jax.experimental import enable_x64
-
     labels_j = jnp.asarray(np.asarray(labels, dtype=np.int32))
     colors_j = jnp.asarray(np.asarray(cluster_colors, dtype=np.int32))
-    with enable_x64():          # int64 (color, cluster) sort keys
+    with jax.enable_x64(True):          # int64 (color, cluster) sort keys
         row_order, nclusters, maxlen, _ = _pack_analyze_device(
             labels_j, colors_j, num_colors=num_colors)
         ncl = np.asarray(nclusters[:num_colors])        # [C] ints (geometry)

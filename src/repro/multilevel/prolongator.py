@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from ..graphs.csr import ELLMatrix
 
 INT32_MAX = np.int32(2**31 - 1)
@@ -84,36 +85,44 @@ def _prolongator_scan_device(a_cols, a_vals, a_mask, labels, omega):
     host path.
     """
     v, d = a_cols.shape
+    # slot-major [D, V] internally (outputs transposed back): per-slot
+    # steps are then lane-dense [V] vectors, which the TPU compiler turns
+    # around in seconds instead of minutes
+    cols_t, vals_t, mask_t = a_cols.T, a_vals.T, a_mask.T
     rid = jnp.arange(v, dtype=jnp.int32)
     # aggregate sizes + tentative scaling (labels are all >= 0 here)
     aggsize = jnp.zeros(v, jnp.int32).at[labels].add(1)
-    inv_sqrt = 1.0 / jnp.sqrt(aggsize.astype(jnp.float64))
+    # the barrier keeps XLA from rewriting 1/sqrt into rsqrt, which is not
+    # correctly rounded (numpy/scipy divide by the rounded sqrt)
+    inv_sqrt = 1.0 / jax.lax.optimization_barrier(
+        jnp.sqrt(aggsize.astype(jnp.float64)))
     # diagonal: canonical rows hold at most one self entry
-    diag = jnp.sum(jnp.where((a_cols == rid[:, None]) & a_mask,
-                             a_vals, jnp.float32(0)), axis=1)
+    diag = jnp.sum(jnp.where((cols_t == rid[None, :]) & mask_t,
+                             vals_t, jnp.float32(0)), axis=0)
     dinv = 1.0 / diag.astype(jnp.float64)
     # per-slot candidates of X = A @ P0 (term order = CSR slot order)
-    cand_col = jnp.where(a_mask, labels[a_cols], INT32_MAX)
-    contrib = jnp.where(a_mask,
-                        a_vals.astype(jnp.float64) * inv_sqrt[labels[a_cols]],
+    lab_n = labels[cols_t]
+    cand_col = jnp.where(mask_t, lab_n, INT32_MAX)
+    contrib = jnp.where(mask_t, vals_t.astype(jnp.float64) * inv_sqrt[lab_n],
                         0.0)
     # stable sort by column keeps equal-column terms in slot order
-    order = jnp.argsort(cand_col, axis=1, stable=True)
-    col_s = jnp.take_along_axis(cand_col, order, axis=1)
-    con_s = jnp.take_along_axis(contrib, order, axis=1)
+    order = jnp.argsort(cand_col, axis=0, stable=True)
+    col_s = jnp.take_along_axis(cand_col, order, axis=0)
+    con_s = jnp.take_along_axis(contrib, order, axis=0)
     # sequential run sums (SMMP accumulation order): s_i = sum of the
     # following same-column slots, added one shift at a time
-    pad_c = jnp.pad(col_s, ((0, 0), (0, d)), constant_values=-1)
-    pad_v = jnp.pad(con_s, ((0, 0), (0, d)), constant_values=0.0)
+    pad_c = jnp.pad(col_s, ((0, d), (0, 0)), constant_values=-1)
+    pad_v = jnp.pad(con_s, ((0, d), (0, 0)), constant_values=0.0)
     s = con_s
     for off in range(1, d):
-        s = s + jnp.where(pad_c[:, off:off + d] == col_s,
-                          pad_v[:, off:off + d], 0.0)
-    tent = jnp.where(col_s == labels[:, None], inv_sqrt[labels][:, None], 0.0)
+        s = s + jnp.where(pad_c[off:off + d] == col_s, pad_v[off:off + d],
+                          0.0)
+    tent = jnp.where(col_s == labels[None, :], inv_sqrt[labels][None, :],
+                     0.0)
     # scipy's `omega * sp.diags(d_inv) @ X` binds as (omega*d_inv) @ X —
     # same association here keeps the f64 values bit-identical
-    smoothed = (omega * dinv)[:, None] * s
-    return col_s, tent, smoothed, diag
+    smoothed = (omega * dinv)[None, :] * s
+    return col_s.T, tent.T, smoothed.T, diag
 
 
 @jax.jit
@@ -128,17 +137,18 @@ def _prolongator_finish_device(col_s, tent, smoothed):
     val 0.0`` so they are inert inside the Galerkin expansion.
     """
     v = col_s.shape[0]
+    col_t = col_s.T                             # slot-major, as in the scan
     head = jnp.concatenate(
-        [jnp.ones((v, 1), bool), col_s[:, 1:] != col_s[:, :-1]], axis=1)
-    real = col_s != INT32_MAX
-    pval = tent - smoothed
+        [jnp.ones((1, v), bool), col_t[1:] != col_t[:-1]], axis=0)
+    real = col_t != INT32_MAX
+    pval = tent.T - smoothed.T
     keep = head & real & (pval != 0.0)          # scipy binop drops exact 0s
-    p_cols = jnp.where(keep, col_s, 0)
+    p_cols = jnp.where(keep, col_t, 0)
     p_vals = jnp.where(keep, pval, 0.0)
-    dp_real = jnp.max(jnp.sum(keep, axis=1))
+    dp_real = jnp.max(jnp.sum(keep, axis=0))
     rcounts = jnp.zeros(v + 1, jnp.int32).at[
         jnp.where(keep, p_cols, v)].add(1)[:-1]
-    return p_cols, p_vals, keep, dp_real, jnp.max(rcounts)
+    return p_cols.T, p_vals.T, keep.T, dp_real, jnp.max(rcounts)
 
 
 def _prolongator_device(a_cols, a_vals, a_mask, labels, omega):
@@ -161,28 +171,31 @@ def _prolongator_pack_device(p_cols, p_vals64, p_keep, *,
     sorted by column — bitwise the host layout)."""
     v, d = p_cols.shape
     pw, rw = max(1, p_width), max(1, r_width)
-    vals32 = p_vals64.astype(jnp.float32)
+    # slot-major [D, V] scatter indices (see _prolongator_scan_device)
+    cols_t, keep_t = p_cols.T, p_keep.T
+    vals32 = p_vals64.T.astype(jnp.float32)
     # P: within-row compaction of the kept heads (already column-sorted)
-    slot = jnp.cumsum(p_keep.astype(jnp.int32), axis=1) - 1
-    rows = jnp.where(p_keep, jnp.arange(v, dtype=jnp.int32)[:, None], v)
+    slot = jnp.cumsum(keep_t.astype(jnp.int32), axis=0) - 1
+    rows = jnp.where(keep_t, jnp.arange(v, dtype=jnp.int32)[None, :], v)
     sl = jnp.clip(slot, 0, pw - 1)
     pe_cols = jnp.zeros((v, pw), jnp.int32).at[rows, sl].set(
-        p_cols, mode="drop")
+        cols_t, mode="drop")
     pe_vals = jnp.zeros((v, pw), jnp.float32).at[rows, sl].set(
         vals32, mode="drop")
     pe_mask = jnp.zeros((v, pw), bool).at[rows, sl].set(True, mode="drop")
     # R = P^T: entries sorted by (coarse row, fine col) via one stable sort
-    vids = jnp.repeat(jnp.arange(v, dtype=jnp.int64)[:, None], d, axis=1)
-    keys = jnp.where(p_keep, p_cols.astype(jnp.int64) * v + vids,
+    # (keys are unique per kept entry, so the flattening order is free)
+    vids = jnp.arange(v, dtype=jnp.int64)[None, :]
+    keys = jnp.where(keep_t, cols_t.astype(jnp.int64) * v + vids,
                      jnp.int64(num_aggregates) * v + v).reshape(-1)
-    order = jnp.argsort(keys, stable=True)
+    order = xla_ops.argsort_int64(keys)
     keys_s = keys[order]
     vals_s = vals32.reshape(-1)[order]
     kept = keys_s < jnp.int64(num_aggregates) * v
     crow = jnp.where(kept, (keys_s // v).astype(jnp.int32), num_aggregates)
     ccol = (keys_s % v).astype(jnp.int32)
     counts = jnp.zeros(num_aggregates + 1, jnp.int32).at[crow].add(1)[:-1]
-    starts = jnp.cumsum(counts) - counts
+    starts = xla_ops.cumsum(counts) - counts
     rank = jnp.arange(keys_s.shape[0], dtype=jnp.int32)
     rslot = jnp.clip(rank - starts[jnp.clip(crow, 0, num_aggregates - 1)],
                      0, rw - 1)

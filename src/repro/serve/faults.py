@@ -150,8 +150,10 @@ class RetryPolicy:
 
     *Transient* engine failures (``InjectedFault(transient=True)`` — the
     recoverable-blip model) are retried up to ``max_attempts`` total
-    attempts with capped exponential backoff.  Any other engine failure,
-    or an exhausted retry budget, degrades to the **fallback engine** —
+    attempts with capped exponential backoff.  Any other engine failure
+    except a compile or lowering error (:func:`is_compile_error`, which
+    fails the request), or an exhausted retry budget, degrades to the
+    **fallback engine** —
     the host/dense referent every parity gate in the repo anchors on
     (``mis2 -> dense``, ``amg_setup -> host``, coloring/coarsening -> the
     default facade path).  Fallback results flow through the same digest
@@ -169,6 +171,31 @@ class RetryPolicy:
         (1-based)."""
         return min(self.max_backoff_s,
                    self.base_backoff_s * (2.0 ** (attempt - 1)))
+
+
+#: substrings of an XLA runtime error raised while compiling a program
+_COMPILE_MARKERS = ("compil", "mosaic", "lowering")
+
+
+def is_compile_error(err: BaseException) -> bool:
+    """True iff ``err`` (or an exception it chains) is a kernel-lowering or
+    program-compile failure: a defect of the engine on this platform.
+
+    A fallback engine would hide such a defect behind a correct answer,
+    so the server fails the request instead of degrading it."""
+    import jax
+
+    seen = set()
+    while err is not None and id(err) not in seen:
+        seen.add(id(err))
+        if isinstance(err, NotImplementedError) \
+                or type(err).__name__ == "LoweringException":
+            return True
+        if isinstance(err, jax.errors.JaxRuntimeError) and any(
+                m in str(err).lower() for m in _COMPILE_MARKERS):
+            return True
+        err = err.__cause__ or err.__context__
+    return False
 
 
 #: the engine-contract referent each kind degrades to (None = the facade

@@ -66,7 +66,8 @@ from .batcher import Batcher, PendingRequest, _freeze
 from .cache import ResultCache
 from .errors import (DeadlineExceeded, DigestMismatch, EngineFailure,
                      LayoutInfeasible, ServeError, ServerClosed)
-from .faults import FALLBACK_ENGINES, FaultPlan, InjectedFault, RetryPolicy
+from .faults import (FALLBACK_ENGINES, FaultPlan, InjectedFault, RetryPolicy,
+                     is_compile_error)
 from .persist import PersistTier
 from .streaming import StreamSession
 from .warm import WarmRegistry, WarmSpec
@@ -563,7 +564,10 @@ class Server:
         budget, or a real engine exception degrades each request to its
         fallback engine (``serve.fallbacks{from,to}``) — the host/dense
         referent of the engine contract.  Only if the fallback *also*
-        fails does the group error (wrapped ``EngineFailure``).
+        fails does the group error (wrapped ``EngineFailure``).  A compile
+        or lowering error is never degraded: it fails the group as an
+        ``EngineFailure``, so a kernel that does not build on this
+        platform cannot come back as another engine's answer.
         """
         faults = self.config.faults
         policy = self.config.retry
@@ -591,6 +595,13 @@ class Server:
             except ServeError:
                 raise
             except Exception as err:
+                if is_compile_error(err):
+                    failure = EngineFailure(
+                        f"kind={reqs[0].kind!r}: the engine failed to "
+                        f"compile on this platform (not degraded to a "
+                        f"fallback engine): {err}")
+                    failure.__cause__ = err
+                    raise failure
                 if policy.fallback:
                     return [self._fallback(req, err) for req in reqs]
                 raise

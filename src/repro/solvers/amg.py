@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import xla_ops
 from .._compat import DeprecatedMapping, warn_deprecated
 from ..core.aggregation import (
     _aggregate_basic_impl,
@@ -71,7 +72,7 @@ def _spmv(ell: ELLMatrix, x):
 @functools.partial(jax.jit, static_argnames=("sweeps",))
 def _jacobi(ell_cols, ell_vals, diag, x, b, weight, sweeps: int):
     for _ in range(sweeps):
-        ax = jnp.sum(ell_vals * x[ell_cols], axis=1)
+        ax = jnp.sum(ell_vals * xla_ops.gather_rows(x, ell_cols), axis=1)
         x = x + weight * (b - ax) / diag
     return x
 

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import xla_ops
 from ..graphs.csr import ELLMatrix, csr_to_ell_matrix
 from ..graphs.handle import Graph
 from ..graphs.ops import extract_diagonal
@@ -67,7 +68,7 @@ def _row_update(cols, vals, diag, x, b, rows):
     safe = jnp.clip(rows, 0, v - 1)
     a_cols = cols[safe]                         # [R, D]
     a_vals = vals[safe]
-    ax = jnp.sum(a_vals * x[a_cols], axis=1)    # A_i . x
+    ax = jnp.sum(a_vals * xla_ops.gather_rows(x, a_cols), axis=1)    # A_i . x
     xi = x[safe]
     new = xi + (b[safe] - ax) / diag[safe]
     return x.at[rows].set(new, mode="drop")

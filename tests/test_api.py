@@ -178,6 +178,41 @@ def test_backend_interpret_auto_matches_device():
     assert Backend(interpret=False).resolve_interpret() is False
 
 
+def test_backend_interpret_follows_pinned_device(monkeypatch):
+    """A request pinned to the CPU device interprets even when the
+    process default backend is an accelerator."""
+    import jax
+
+    from repro.api import backend as backend_mod
+
+    monkeypatch.setattr(backend_mod, "accelerator_present", lambda: True)
+    assert Backend().resolve_interpret() is False
+    cpu = Backend(device=jax.devices("cpu")[0])
+    assert cpu.resolve_interpret() is True
+
+
+def test_compile_cache_dir_rule(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache sits at the fixed in-checkout path."""
+    import jax
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.setup_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        got = compile_cache.setup_compile_cache()
+        assert got == str(compile_cache.DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        assert (compile_cache.DEFAULT_CACHE_DIR.parent / "src" / "repro"
+                ).is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_backend_threads_through_pallas_engine():
     g = graph_cases()["laplace3d"]
     base = mis2(g)

@@ -172,7 +172,7 @@ def test_fused_refresh_columns_bit_exact(count_frac):
     b = id_bits(ell.num_vertices)
     it = jnp.uint32(4)
     out_k = fused_refresh_columns_pallas(
-        t, jnp.asarray(ell.neighbors).reshape(-1), wl,
+        t, jnp.asarray(ell.neighbors), wl,
         jnp.int32(count), it, priority="xorshift_star", b=b)
     out_r = fused_refresh_columns_ref(t, ell.neighbors, wl, count, it,
                                       "xorshift_star", b)
@@ -190,7 +190,7 @@ def test_fused_decide_bit_exact(count_frac):
     b = id_bits(ell.num_vertices)
     it = jnp.uint32(2)
     out_k = fused_decide_pallas(
-        t, m, active, jnp.asarray(ell.neighbors).reshape(-1), wl,
+        t, m, active, jnp.asarray(ell.neighbors), wl,
         jnp.int32(count), it, priority="xorshift_star", b=b)
     out_r = fused_decide_ref(t, m, active, ell.neighbors, wl, count, it,
                              "xorshift_star", b)
@@ -209,9 +209,10 @@ def test_ell_row_traffic_model():
 
 
 def test_fused_wrappers_take_indices_not_gathered_rows():
-    """Structural guarantee behind the traffic model: the fused kernels
-    consume worklist indices + the flat adjacency (in-kernel gather), not
-    pre-gathered ``[W, D]`` row copies like the legacy pair."""
+    """Structural guarantee behind the traffic model: the fused wrappers
+    consume worklist indices + the adjacency (the row gather traces into
+    the same program as the kernel), not pre-gathered ``[W, D]`` row
+    copies like the legacy pair."""
     import inspect
 
     from repro.kernels.minprop_ell import kernel
@@ -220,7 +221,7 @@ def test_fused_wrappers_take_indices_not_gathered_rows():
     fused = inspect.signature(kernel.fused_refresh_columns_pallas)
     assert "wl_neighbors" in legacy.parameters        # the [W, D] copy
     assert "wl_neighbors" not in fused.parameters
-    assert {"nbrs_flat", "wl"} <= set(fused.parameters)
+    assert {"neighbors", "wl"} <= set(fused.parameters)
 
 
 # ---------------------------------------------------------------------------

@@ -226,10 +226,35 @@ def test_engine_resolution_honors_request_backend_platform():
     assert default_mis2_engine(tpu_req.with_(pallas=True)) == \
         "pallas_resident"
     assert default_multilevel_engine(cpu_req) == "host"
-    assert default_multilevel_engine(tpu_req) == "resident"
+    # XLA emulates f64 on the TPU (not IEEE): the AMG setup stays on the
+    # host engine there; the integer-only cluster-GS setup goes resident
+    assert default_multilevel_engine(tpu_req) == "host"
+    assert default_multilevel_engine(tpu_req, kind="cluster_gs") == \
+        "resident"
+    gpu_req = Backend(device=_FakeDevice("gpu"))
+    assert default_multilevel_engine(gpu_req) == "resident"
     # the worklists=False ablation still forces the host-driven driver
     assert default_mis2_engine(
         tpu_req, Mis2Options(worklists=False)) == "compacted"
+
+
+def test_resident_amg_setup_refused_without_ieee_f64():
+    """The resident AMG setup would build a different hierarchy on the
+    TPU's emulated f64, so it is refused with a typed error before any
+    placement or compile (and the serve path must not degrade it)."""
+    from repro.api import InexactFloat64Error
+    from repro.serve.faults import is_compile_error
+
+    tpu_req = Backend(device=_FakeDevice("tpu"))
+    g = repro.Graph(laplace3d(3))
+    with pytest.raises(InexactFloat64Error, match="resident"):
+        repro.amg_setup(g, engine="resident", backend=tpu_req)
+    with pytest.raises(InexactFloat64Error):
+        repro.amg_setup_batch([g], engine="resident", backend=tpu_req)
+    try:
+        repro.amg_setup(g, engine="resident", backend=tpu_req)
+    except InexactFloat64Error as err:
+        assert is_compile_error(err)
 
 
 def test_server_resolves_engine_per_request(fleet):

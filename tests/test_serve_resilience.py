@@ -432,6 +432,25 @@ def test_real_engine_exception_wrapped_as_engine_failure():
     assert ei.value.__cause__ is boom
 
 
+def test_compile_error_fails_request_instead_of_falling_back():
+    """A kernel that does not lower on this platform is an engine defect:
+    the default fallback policy must not hide it behind the dense
+    referent's (correct) answer."""
+    srv = Server(ServerConfig())
+    lowering = NotImplementedError("Only 2D gather is supported")
+
+    def failing(reqs):
+        raise RuntimeError("pallas_call failed") from lowering
+
+    srv._compute = failing
+    fut = srv.submit("mis2", _graph(37))
+    srv.flush()
+    with pytest.raises(EngineFailure, match="failed to compile") as ei:
+        fut.result(timeout=5)
+    assert ei.value.__cause__.__cause__ is lowering
+    assert srv.stats.fallbacks == 0
+
+
 # ---------------------------------------------------------------------------
 # the digest ledger: one key, one digest, forever
 # ---------------------------------------------------------------------------
