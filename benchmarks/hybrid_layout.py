@@ -12,8 +12,8 @@ Measured per scale:
   accounting (slice widths/rows, spill rows/entries, padded bytes vs. the
   monolithic estimate, padding ratio);
 * MIS-2 (``engine="pallas_hybrid"``) — solve wall time, iterations, the
-  §V-D row-traffic model bytes, and the compile accounting (the resident
-  fixed point is ONE dispatch; jit churn is O(#slices), not O(graph));
+  slab rows processed, and the compile accounting (the resident fixed
+  point is ONE dispatch; jit churn is O(#slices), not O(graph));
 * two-phase coarsening (``mis2_engine="pallas_hybrid"``) — end-to-end
   Algorithm 3 over the hybrid join loops: wall time, aggregate count,
   coarsening ratio.
@@ -92,7 +92,7 @@ def run(quick: bool = False) -> None:
                     f"{hyb.padding_ratio:.3f}")},
         {"stage": "mis2_hybrid", "seconds": mis2_s, "V": v,
          "detail": (f"iterations={r.iterations} compiles={r.num_compiles} "
-                    f"row_bytes={c['row_bytes_total']}")},
+                    f"slab_rows={sum(c['slice_rows_processed'])}")},
         {"stage": "coarsen_two_phase_hybrid", "seconds": coarsen_s, "V": v,
          "detail": (f"aggregates={agg.num_aggregates} ratio="
                     f"{agg.coarsening_ratio:.2f}")},
@@ -117,7 +117,7 @@ def run(quick: bool = False) -> None:
         "mis2_s": round(mis2_s, 4),
         "mis2_iterations": int(r.iterations),
         "mis2_num_compiles": int(r.num_compiles),
-        "mis2_row_bytes": int(c["row_bytes_total"]),
+        "mis2_slab_rows": int(sum(c["slice_rows_processed"])),
         "coarsen_s": round(coarsen_s, 4),
         "num_aggregates": int(agg.num_aggregates),
         "coarsening_ratio": round(agg.coarsening_ratio, 3),

@@ -27,6 +27,7 @@ from .. import xla_ops
 from .._compat import warn_deprecated
 from ..graphs.csr import ELLGraph
 from ..graphs.handle import as_graph
+from ..obs import span as _obs_span
 from .mis2 import Mis2Options, run_mis2
 
 INT32_MAX = np.int32(2**31 - 1)
@@ -441,8 +442,9 @@ def _aggregate_two_phase_hybrid_impl(
 
     r1 = run_mis2(gh, options=options, engine="pallas_hybrid",
                   interpret=interpret)
-    labels, nagg = _labels_from_roots_hybrid(hyb, r1.in_set)
-    phase = np.where(labels >= 0, 1, 0).astype(np.uint8)
+    with _obs_span("coarsen.root_join"):
+        labels, nagg = _labels_from_roots_hybrid(hyb, r1.in_set)
+        phase = np.where(labels >= 0, 1, 0).astype(np.uint8)
     total_iters = r1.iterations
     converged = r1.converged
 
@@ -453,22 +455,22 @@ def _aggregate_two_phase_hybrid_impl(
                       engine="pallas_hybrid", interpret=interpret)
         total_iters += r2.iterations
         converged = converged and r2.converged
-        labels_j, roots2_j, newly_j = _phase2_join_resident_hybrid(
-            *parts, jnp.asarray(labels.astype(np.int32)),
-            jnp.asarray(r2.in_set), jnp.int32(nagg),
-            min_secondary_neighbors)
-        labels, roots2 = np.asarray(labels_j), np.asarray(roots2_j)
-        phase[np.asarray(newly_j)] = 2
-        nagg += int(roots2.sum())
+        with _obs_span("coarsen.phase2_join"):
+            labels_j, roots2_j, newly_j = _phase2_join_resident_hybrid(
+                *parts, jnp.asarray(labels.astype(np.int32)),
+                jnp.asarray(r2.in_set), jnp.int32(nagg),
+                min_secondary_neighbors)
+            labels, roots2 = np.asarray(labels_j), np.asarray(roots2_j)
+            phase[np.asarray(newly_j)] = 2
+            nagg += int(roots2.sum())
 
-    labels_j, phase_j = _phase3_resident_hybrid(
-        *parts, jnp.asarray(labels.astype(np.int32)), jnp.asarray(phase))
-    labels, phase = np.asarray(labels_j), np.array(phase_j)
+    with _obs_span("coarsen.phase3_join"):
+        labels_j, phase_j = _phase3_resident_hybrid(
+            *parts, jnp.asarray(labels.astype(np.int32)), jnp.asarray(phase))
+        labels, phase = np.asarray(labels_j), np.array(phase_j)
 
-    labels, nagg = _finalize_singletons(labels, nagg, phase)
-    return AggregationResult(labels.astype(np.int32), nagg,
-                             r1.in_set | roots2, phase, total_iters,
-                             converged)
+    return _finalize(labels, nagg, phase, r1.in_set | roots2, total_iters,
+                     converged)
 
 
 # ---------------------------------------------------------------------------
@@ -520,8 +522,9 @@ def _aggregate_two_phase_impl(graph, options: Mis2Options | None = None,
     # Phase 1: MIS-2 roots + direct neighbors
     r1 = run_mis2(gh, options=options, engine=engine, interpret=interpret,
                   mesh=mesh, axis=axis)
-    labels, nagg = _labels_from_roots(ell, r1.in_set)
-    phase = np.where(labels >= 0, 1, 0).astype(np.uint8)
+    with _obs_span("coarsen.root_join"):
+        labels, nagg = _labels_from_roots(ell, r1.in_set)
+        phase = np.where(labels >= 0, 1, 0).astype(np.uint8)
     total_iters = r1.iterations
     converged = r1.converged
 
@@ -536,25 +539,35 @@ def _aggregate_two_phase_impl(graph, options: Mis2Options | None = None,
                       axis=axis)
         total_iters += r2.iterations
         converged = converged and r2.converged
-        labels_j, roots2_j, newly_j = _phase2_join_resident(
-            ell.neighbors, ell.mask, jnp.asarray(labels.astype(np.int32)),
-            jnp.asarray(r2.in_set), jnp.int32(nagg),
-            min_secondary_neighbors)
-        labels, roots2 = np.asarray(labels_j), np.asarray(roots2_j)
-        phase[np.asarray(newly_j)] = 2
-        nagg += int(roots2.sum())
+        with _obs_span("coarsen.phase2_join"):
+            labels_j, roots2_j, newly_j = _phase2_join_resident(
+                ell.neighbors, ell.mask,
+                jnp.asarray(labels.astype(np.int32)),
+                jnp.asarray(r2.in_set), jnp.int32(nagg),
+                min_secondary_neighbors)
+            labels, roots2 = np.asarray(labels_j), np.asarray(roots2_j)
+            phase[np.asarray(newly_j)] = 2
+            nagg += int(roots2.sum())
 
     # Phase 3: max-coupling join against frozen tentative labels — the
     # whole up-to-4-round loop is one resident dispatch
-    labels_j, phase_j = _phase3_resident(
-        ell.neighbors, ell.mask, jnp.asarray(labels.astype(np.int32)),
-        jnp.asarray(phase))
-    labels, phase = np.asarray(labels_j), np.array(phase_j)
+    with _obs_span("coarsen.phase3_join"):
+        labels_j, phase_j = _phase3_resident(
+            ell.neighbors, ell.mask, jnp.asarray(labels.astype(np.int32)),
+            jnp.asarray(phase))
+        labels, phase = np.asarray(labels_j), np.array(phase_j)
 
-    labels, nagg = _finalize_singletons(labels, nagg, phase)
-    return AggregationResult(labels.astype(np.int32), nagg,
-                             r1.in_set | roots2, phase, total_iters,
-                             converged)
+    return _finalize(labels, nagg, phase, r1.in_set | roots2, total_iters,
+                     converged)
+
+
+def _finalize(labels, nagg, phase, roots, total_iters, converged):
+    """Alg. 3's host finalisation (singletons, the result), in the
+    ``coarsen.finalize`` span."""
+    with _obs_span("coarsen.finalize"):
+        labels, nagg = _finalize_singletons(labels, nagg, phase)
+        return AggregationResult(labels.astype(np.int32), nagg, roots,
+                                 phase, total_iters, converged)
 
 
 # ---------------------------------------------------------------------------

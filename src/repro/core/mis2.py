@@ -314,6 +314,10 @@ class HotLoopStats:
 
 HOTLOOP_STATS = HotLoopStats()
 
+#: rounds run by the resident fixed points, labelled by layout
+#: (``ell`` | ``csr_segment`` | ``hybrid``)
+ROUNDS = "mis2.rounds"
+
 
 # ===========================================================================
 # step kernels for the compacted / ablation engine
@@ -820,23 +824,38 @@ def _mis2_resident_impl(graph, active: Optional[np.ndarray] = None,
 
     with _obs_span("mis2.resident_fixed_point", layout=options.layout,
                    pallas=pallas, packed=options.packed, v=v) as sp:
-        if options.layout == "ell":
-            t, it, n1 = _resident_ell_fixed_point(
-                gh.ell.neighbors, active_j, priority=options.priority,
-                packed=options.packed, max_iters=options.max_iters, b=b,
-                use_pallas=pallas, interpret=interpret)
-        else:
-            edge_rows, edge_cols = gh.csr_edges
-            t, it, n1 = _resident_csr_fixed_point(
-                edge_rows, edge_cols, active_j, priority=options.priority,
-                packed=options.packed, max_iters=options.max_iters, b=b, v=v)
+        with _obs_span("mis2.launch"):
+            if options.layout == "ell":
+                t, it, n1 = _resident_ell_fixed_point(
+                    gh.ell.neighbors, active_j, priority=options.priority,
+                    packed=options.packed, max_iters=options.max_iters, b=b,
+                    use_pallas=pallas, interpret=interpret)
+            else:
+                edge_rows, edge_cols = gh.csr_edges
+                t, it, n1 = _resident_csr_fixed_point(
+                    edge_rows, edge_cols, active_j, priority=options.priority,
+                    packed=options.packed, max_iters=options.max_iters, b=b,
+                    v=v)
         _OBS.counter(HotLoopStats._DISPATCHES).inc()
-        jax.block_until_ready(t)    # span duration covers device execution
-        sp.annotate(iterations=int(it))
+        iterations = wait_rounds(sp, t, it, options.layout)
 
-    t_np = np.asarray(t)
-    in_set = (t_np == np.uint32(IN)) if options.packed else (t_np == S_IN)
-    return Mis2Result(in_set, int(it), int(n1) == 0, num_compiles=1)
+    with _obs_span("mis2.pull"):
+        t_np = np.asarray(t)
+        in_set = (t_np == np.uint32(IN)) if options.packed \
+            else (t_np == S_IN)
+        return Mis2Result(in_set, iterations, int(n1) == 0, num_compiles=1)
+
+
+def wait_rounds(sp, t, it, layout: str) -> int:
+    """Close a resident fixed point: wait for the device in a
+    ``mis2.wait`` span (so the enclosing span covers device execution),
+    then record the rounds run on ``sp`` and in ``mis2.rounds{layout}``."""
+    with _obs_span("mis2.wait"):
+        jax.block_until_ready(t)
+    iterations = int(it)
+    sp.annotate(iterations=iterations)
+    _OBS.counter(ROUNDS, labels={"layout": layout}).inc(iterations)
+    return iterations
 
 
 # ===========================================================================

@@ -15,14 +15,12 @@ the monolithic engines (``dense``, ``pallas_resident``) for equal options
 — the standing digest-parity gate extends over adversarial degree
 distributions in ``tests/test_hybrid.py``.
 
-Traffic accounting: the loop state carries one int32 counter per slice
+Work accounting: the loop state carries one int32 counter per slice
 (live worklist rows processed, both phases), and the spill contributes
-two segment sweeps per round.  The ``ELL_ROW_TRAFFIC``-style model
-(``kernels.minprop_ell.ops.hybrid_row_traffic_bytes``) converts those
-counts to bytes; the engine mirrors the total into the ``repro.obs``
-registry (``mis2.hybrid_row_bytes``) and onto the result, and the
-``hybrid_traffic`` gate in ``tools/check_shape.py`` asserts all three
-agree.
+two segment sweeps per round; both land in ``result.collectives``
+(``slice_rows_processed``, ``spill_passes``).  The rounds run go to the
+``repro.obs`` counter ``mis2.rounds{layout=hybrid}``, and the
+``hybrid_traffic`` gate in ``tools/check_shape.py`` asserts them.
 """
 from __future__ import annotations
 
@@ -42,10 +40,9 @@ from .mis2 import (
     Mis2Options,
     Mis2Result,
     compact_worklist,
+    wait_rounds,
 )
 from .tuples import IN, id_bits, is_undecided
-
-HYBRID_ROW_BYTES = "mis2.hybrid_row_bytes"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -119,7 +116,6 @@ def _mis2_hybrid_impl(graph, active: Optional[np.ndarray] = None,
     degree-aware layout; works where the monolithic padded ELL cannot even
     be allocated."""
     from ..kernels._interpret import resolve_interpret
-    from ..kernels.minprop_ell.ops import hybrid_row_traffic_bytes
 
     options = Mis2Options() if options is None else options
     if not options.worklists:
@@ -142,30 +138,24 @@ def _mis2_hybrid_impl(graph, active: Optional[np.ndarray] = None,
     with _obs_span("mis2.hybrid_fixed_point", layout="hybrid",
                    num_slices=hyb.num_slices,
                    spill_rows=hyb.num_spill_rows, v=v) as sp:
-        t, it, n1, acc = _hybrid_fixed_point(
-            hyb.slices, hyb.spill_rows, hyb.spill_seg, hyb.spill_cols,
-            active_j, priority=options.priority, max_iters=options.max_iters,
-            b=b, interpret=interp)
+        with _obs_span("mis2.launch"):
+            t, it, n1, acc = _hybrid_fixed_point(
+                hyb.slices, hyb.spill_rows, hyb.spill_seg, hyb.spill_cols,
+                active_j, priority=options.priority,
+                max_iters=options.max_iters, b=b, interpret=interp)
         _OBS.counter(HotLoopStats._DISPATCHES).inc()
-        jax.block_until_ready(t)    # span duration covers device execution
-        sp.annotate(iterations=int(it))
+        iterations = wait_rounds(sp, t, it, "hybrid")
 
-    iterations = int(it)
-    rows_processed = [int(x) for x in np.asarray(acc)[:hyb.num_slices]]
-    spill_passes = 2 * iterations if hyb.num_spill_rows else 0
-    row_bytes = hybrid_row_traffic_bytes(
-        hyb.slice_widths, rows_processed, hyb.num_spill_entries, spill_passes)
-    _OBS.counter(HYBRID_ROW_BYTES).inc(row_bytes)
-
-    t_np = np.asarray(t)
-    return Mis2Result(
-        t_np == np.uint32(IN), iterations, int(n1) == 0,
-        collectives={
-            "variant": "hybrid",
-            "row_bytes_total": row_bytes,
-            "slice_widths": list(hyb.slice_widths),
-            "slice_rows_processed": rows_processed,
-            "spill_entries": hyb.num_spill_entries,
-            "spill_passes": spill_passes,
-        },
-        num_compiles=1)
+    with _obs_span("mis2.pull"):
+        rows_processed = [int(x) for x in np.asarray(acc)[:hyb.num_slices]]
+        t_np = np.asarray(t)
+        return Mis2Result(
+            t_np == np.uint32(IN), iterations, int(n1) == 0,
+            collectives={
+                "variant": "hybrid",
+                "slice_widths": list(hyb.slice_widths),
+                "slice_rows_processed": rows_processed,
+                "spill_entries": hyb.num_spill_entries,
+                "spill_passes": 2 * iterations if hyb.num_spill_rows else 0,
+            },
+            num_compiles=1)

@@ -19,8 +19,11 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs import span as _obs_span
 
 Array = jnp.ndarray
 
@@ -139,6 +142,16 @@ def _csr_host(indptr, indices):
     return np.asarray(indptr), np.asarray(indices)
 
 
+def to_device(host):
+    """Copy a pytree of host arrays to the default device in one
+    ``device_put``, inside a ``graph.to_device`` span that waits for the
+    copy to land, so the span covers the transfer."""
+    with _obs_span("graph.to_device"):
+        out = jax.device_put(host)
+        jax.block_until_ready(out)
+    return out
+
+
 def csr_to_ell_graph(g: CSRGraph, width: int | None = None) -> ELLGraph:
     """CSR -> ELL. ``width`` defaults to the max degree (rows longer than
     ``width`` would be truncated; we require width >= max degree)."""
@@ -155,7 +168,7 @@ def csr_to_ell_graph(g: CSRGraph, width: int | None = None) -> ELLGraph:
     rows = np.repeat(np.arange(v), deg)
     neighbors[rows, slot] = indices
     mask[rows, slot] = True
-    return ELLGraph(jnp.asarray(neighbors), jnp.asarray(mask))
+    return ELLGraph(*to_device((neighbors, mask)))
 
 
 def csr_to_ell_matrix(m: CSRMatrix, width: int | None = None) -> ELLMatrix:

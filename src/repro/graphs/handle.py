@@ -19,7 +19,9 @@ number of times the *work* was actually performed.  Tests assert a second
 timed (``graph.conversion_timings``) and mirrored into the process-wide
 ``repro.obs`` registry as ``graph.conversions{kind=...}`` /
 ``graph.conversion_seconds{kind=...}`` so one ``obs.snapshot()`` sees
-format churn next to dispatches and compiles.
+format churn next to dispatches and compiles.  Each conversion runs in a
+``graph.<kind>`` span; the host-to-device copy at its end is the child
+span ``graph.to_device``.
 """
 from __future__ import annotations
 
@@ -31,6 +33,7 @@ import jax
 import numpy as np
 
 from ..obs import metrics as _OBS
+from ..obs import span as _obs_span
 
 from .csr import (
     BucketedELL,
@@ -102,16 +105,18 @@ class Graph:
 
     @contextmanager
     def _convert(self, name: str):
-        """Count + time one conversion's actual work and mirror it into the
-        ``repro.obs`` registry.  Callers hoist prerequisite format accesses
-        (e.g. ``self.csr``) *before* entering, so nested conversions are
+        """Count + time one conversion's actual work, inside a
+        ``graph.<name>`` span, and mirror it into the ``repro.obs``
+        registry.  Callers hoist prerequisite format accesses (e.g.
+        ``self.csr``) *before* entering, so nested conversions are
         attributed to their own kind rather than the outermost one."""
         t0 = time.perf_counter()
         try:
-            yield
-            if self._cache.get("device") is not None:
-                # a placed handle keeps every later format on its device
-                self.place(self._cache["device"])
+            with _obs_span(f"graph.{name}"):
+                yield
+                if self._cache.get("device") is not None:
+                    # a placed handle keeps every later format on its device
+                    self.place(self._cache["device"])
         finally:
             dt = time.perf_counter() - t0
             self._counts[name] = self._counts.get(name, 0) + 1

@@ -42,7 +42,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from .csr import CSRGraph
+from .csr import CSRGraph, to_device
 
 Array = jnp.ndarray
 
@@ -184,8 +184,8 @@ def slice_width_ladder(max_slab_degree: int,
 
 def _build_slab(sel: np.ndarray, deg: np.ndarray, indptr: np.ndarray,
                 indices: np.ndarray, width: int) -> HybridSlice:
-    """Vectorized slab assembly for the selected rows (no per-row loop —
-    this runs at V=1M)."""
+    """Vectorized slab assembly for the selected rows, on the host (no
+    per-row loop — this runs at V=1M)."""
     r = len(sel)
     nbrs = np.repeat(sel.astype(np.int32)[:, None], width, axis=1)
     mask = np.zeros((r, width), dtype=bool)
@@ -196,8 +196,7 @@ def _build_slab(sel: np.ndarray, deg: np.ndarray, indptr: np.ndarray,
     src = np.repeat(indptr[sel].astype(np.int64), dsel) + slot
     nbrs[flat_rows, slot] = indices[src]
     mask[flat_rows, slot] = True
-    return HybridSlice(jnp.asarray(sel.astype(np.int32)),
-                       jnp.asarray(nbrs), jnp.asarray(mask))
+    return HybridSlice(sel.astype(np.int32), nbrs, mask)
 
 
 def csr_to_hybrid_ell(g: CSRGraph, widths: Optional[Sequence[int]] = None,
@@ -207,7 +206,9 @@ def csr_to_hybrid_ell(g: CSRGraph, widths: Optional[Sequence[int]] = None,
     ``widths`` (ascending) overrides the pow2 ladder; ``spill_cap``
     overrides :func:`default_spill_cap`.  Empty buckets produce no slice
     (the kernel stack iterates actual slices, so a graph whose degrees
-    all land in one bucket compiles exactly one slab pass).
+    all land in one bucket compiles exactly one slab pass).  Every array
+    is built on the host first and the layout is copied to the device
+    once (:func:`repro.graphs.csr.to_device`).
     """
     indptr = np.asarray(g.indptr)
     indices = np.asarray(g.indices)
@@ -250,9 +251,7 @@ def csr_to_hybrid_ell(g: CSRGraph, widths: Optional[Sequence[int]] = None,
         [indices[indptr[r]:indptr[r + 1]] for r in hsel]) if len(hsel) \
         else np.zeros(0, dtype=np.int32)
 
-    return HybridEllGraph(
-        tuple(slices),
-        jnp.asarray(hsel.astype(np.int32)),
-        jnp.asarray(spill_seg),
-        jnp.asarray(spill_cols.astype(np.int32)),
-        v, spill_cap)
+    slices, rows, seg, cols = to_device((
+        tuple(slices), hsel.astype(np.int32), spill_seg,
+        spill_cols.astype(np.int32)))
+    return HybridEllGraph(slices, rows, seg, cols, v, spill_cap)

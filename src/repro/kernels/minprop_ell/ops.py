@@ -31,58 +31,6 @@ from .kernel import (
 IN = np.uint32(0)
 OUT = np.uint32(0xFFFFFFFF)
 
-# ---------------------------------------------------------------------------
-# ELL row-traffic model (asserted by tests/test_resident.py)
-#
-# HBM movements of one live worklist row's ELL entries (its neighbor ids)
-# per per-round pass, as the algorithm schedules them: the host-driven
-# path gathers the row in its own dispatch (1 read), materializes the
-# [W, D] worklist copy (1 write), and the gather behind the kernel reads
-# the copy back (1 read) — 3 movements.  The fused resident wrappers
-# gather the ids inside the same program as the T/M gathers, with no
-# worklist copy crossing a dispatch: 1 read.  Not modeled: the gathered
-# tuple tiles the kernels reduce ([D, W] T values, M values, active
-# flags), which the XLA gathers write and the kernels read on every path,
-# and whatever intermediates XLA's gather lowering keeps (device traffic
-# not measured).
-# ---------------------------------------------------------------------------
-
-ELL_ROW_TRAFFIC = {
-    "pallas": {"reads": 2, "writes": 1},
-    "pallas_resident": {"reads": 1, "writes": 0},
-    # hybrid slices reuse the fused gather (1 read of W_i ids per
-    # live worklist row per pass, no materialized copy); the spill segment
-    # is COO, accounted per entry, not per padded row
-    "pallas_hybrid": {"reads": 1, "writes": 0},
-}
-
-
-def ell_row_movements(engine: str) -> int:
-    """Total HBM movements of one worklist row's ELL entries per pass."""
-    t = ELL_ROW_TRAFFIC[engine]
-    return t["reads"] + t["writes"]
-
-
-def hybrid_row_traffic_bytes(slice_widths, slice_rows_processed,
-                             spill_entries: int, spill_passes: int) -> int:
-    """Analytic adjacency traffic of one hybrid MIS-2 solve, in bytes.
-
-    ``slice_rows_processed[i]`` is the total live worklist rows slice ``i``
-    processed across every pass of every round (refresh + decide); each
-    such row moves its ``W_i`` int32 neighbor ids through HBM exactly
-    ``ell_row_movements('pallas_hybrid')`` times.  The spill segment has no
-    worklist: every pass reads all ``spill_entries`` int32 column ids once.
-    The hybrid engine accumulates the same quantities *on device* inside
-    the while_loop; the ``hybrid_traffic`` check_shape gate asserts
-    registry == this model == the result's own accounting.
-    """
-    moves = ell_row_movements("pallas_hybrid")
-    total = 0
-    for w, rows in zip(slice_widths, slice_rows_processed):
-        total += int(rows) * int(w) * 4 * moves
-    total += int(spill_passes) * int(spill_entries) * 4
-    return total
-
 
 @jax.jit
 def _gather_rows(neighbors, wl):

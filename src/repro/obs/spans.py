@@ -18,6 +18,15 @@ Device timing: pass ``fence=<arrays>`` and the span blocks on
 execution rather than async dispatch.  Every closed span also lands one
 observation in the ``span.seconds{span=<name>}`` histogram (names are
 code-defined, so cardinality stays bounded).
+
+Profiler timeline: each span also holds a ``jax.profiler.TraceAnnotation``
+of its own name for its whole life, so a JAX / xprof profile shows the
+program's spans on the host timeline, on the device trace's clock (the
+annotation costs about a microsecond when no profile is recording).
+
+Compiles: the first span registers one ``jax.monitoring`` listener that
+counts every backend compile into the ``jit.compiles`` counter, so a
+span's ``metrics`` say which step compiled.
 """
 from __future__ import annotations
 
@@ -34,7 +43,32 @@ from .registry import metrics as _metrics
 _TLS = threading.local()
 _RECENT_ROOTS: deque = deque(maxlen=64)
 
+#: process-wide count of backend compiles (persistent-cache hits excluded)
+JIT_COMPILES = "jit.compiles"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_JAX: list = []                       # jax, once the compile listener is on
+_JAX_LOCK = threading.Lock()
+
 _SCALARS = (str, int, float, bool, type(None))
+
+
+def _count_compile(event: str, duration: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        _metrics.counter(JIT_COMPILES).inc()
+
+
+def _jax():
+    """``jax``, imported at the first span (``repro.obs`` itself stays
+    free of it); the compile listener is registered exactly once."""
+    if not _JAX:
+        with _JAX_LOCK:
+            if not _JAX:
+                import jax
+
+                jax.monitoring.register_event_duration_secs_listener(
+                    _count_compile)
+                _JAX.append(jax)
+    return _JAX[0]
 
 
 def _stack() -> list:
@@ -79,6 +113,7 @@ def span(name: str, *, fence=None, **attrs):
     span closes so the duration covers device execution.  Keyword attrs
     are serialized into the record (non-scalars via ``str``).
     """
+    jax = _jax()
     base = _metrics.snapshot()
     sp = Span(name,
               {k: v if isinstance(v, _SCALARS) else str(v)
@@ -87,13 +122,14 @@ def span(name: str, *, fence=None, **attrs):
     stack = _stack()
     parent = stack[-1] if stack else None
     stack.append(sp)
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
     try:
         yield sp
     finally:
         if fence is not None:
-            import jax
-
             jax.block_until_ready(fence)
+        annotation.__exit__(None, None, None)
         sp.duration_s = time.perf_counter() - sp.start_s
         sp.metrics = _metrics.snapshot().delta(base).flat()
         stack.pop()

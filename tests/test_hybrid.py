@@ -2,7 +2,7 @@
 invariants, digest parity of the ``pallas_hybrid`` MIS-2 engine (and the
 hybrid coloring / coarsening paths) with the monolithic ELL engines across
 priorities and adversarial degree distributions, the ELL byte-budget guard
-and auto-selection rule, the row-traffic model, and the serve-side
+and auto-selection rule, the work counts, and the serve-side
 ``LayoutInfeasible`` admission shed."""
 import numpy as np
 import pytest
@@ -256,27 +256,25 @@ def test_powerlaw_degree_skew():
 
 
 # ---------------------------------------------------------------------------
-# traffic model + execution shape
+# work counts + execution shape
 # ---------------------------------------------------------------------------
 
 def test_hybrid_traffic_registry_matches_model():
-    from repro.kernels.minprop_ell.ops import (
-        ELL_ROW_TRAFFIC,
-        hybrid_row_traffic_bytes,
-    )
-
-    assert "pallas_hybrid" in ELL_ROW_TRAFFIC
+    """The registry's rounds and the result's work counts agree with the
+    rounds run: two spill passes a round, one resident dispatch."""
     g = graph_cases()["powerlaw"]
     mis2(g, engine="pallas_hybrid")        # warm
     with obs.capture() as cap:
         r = mis2(g, engine="pallas_hybrid")
     c = r.collectives
-    want = hybrid_row_traffic_bytes(c["slice_widths"],
-                                    c["slice_rows_processed"],
-                                    c["spill_entries"], c["spill_passes"])
-    assert cap.value("mis2.hybrid_row_bytes") == want == c["row_bytes_total"]
+    assert r.iterations > 1
+    assert cap.value("mis2.rounds", {"layout": "hybrid"}) == r.iterations
+    assert c["spill_entries"] > 0
+    assert c["spill_passes"] == 2 * r.iterations
+    assert len(c["slice_rows_processed"]) == len(c["slice_widths"])
     assert cap.value("mis2.resident_dispatches") == 1
     assert cap.value("mis2.host_syncs") == 0
+    assert cap.value("jit.compiles") == 0
     assert r.num_compiles == 1
 
 

@@ -387,6 +387,153 @@ def test_graph_conversion_timings_via_snapshot():
 
 
 # ---------------------------------------------------------------------------
+# spans where the work happens: layout, fixed-point launch / wait / pull,
+# the Alg. 3 joins; the rounds and compile counters
+# ---------------------------------------------------------------------------
+
+def _children(span: dict) -> list:
+    return [c["name"] for c in span["children"]]
+
+
+def _find(span: dict, name: str) -> list:
+    found = [span] if span["name"] == name else []
+    for c in span["children"]:
+        found += _find(c, name)
+    return found
+
+
+@pytest.mark.parametrize("engine,layout,fixed_point", [
+    ("compacted_resident", "ell", "mis2.resident_fixed_point"),
+    ("pallas_hybrid", "hybrid", "mis2.hybrid_fixed_point"),
+])
+def test_coarsen_provenance_span_tree(engine, layout, fixed_point):
+    from repro.graphs.generators import powerlaw_graph
+
+    g = powerlaw_graph(700, 8.0, seed=5)
+    coarsen(Graph(g), mis2_engine=engine)            # warm
+    r = coarsen(Graph(g), mis2_engine=engine)
+    root = r.provenance.span
+    conversion = "graph.csr_to_ell" if layout == "ell" \
+        else "graph.csr_to_hybrid"
+    names = _children(root)
+    assert names[names.index(conversion):] == [
+        conversion,
+        fixed_point, "mis2.pull", "coarsen.root_join",
+        fixed_point, "mis2.pull", "coarsen.phase2_join",
+        "coarsen.phase3_join", "coarsen.finalize"]
+    (conv,) = _find(root, conversion)
+    assert _children(conv) == ["graph.to_device"]
+    phases = _find(root, fixed_point)
+    for fp in phases:
+        assert _children(fp) == ["mis2.launch", "mis2.wait"]
+    rounds = [fp["metrics"][f"mis2.rounds{{layout={layout}}}"]
+              for fp in phases]
+    assert rounds == [fp["attrs"]["iterations"] for fp in phases]
+    assert sum(rounds) == r.iterations
+    assert root["metrics"][f"mis2.rounds{{layout={layout}}}"] == r.iterations
+
+
+def test_jit_compiles_counted_in_the_launch_span():
+    # a vertex count no other test uses: its fixed point compiles here
+    g = random_uniform_graph(613, 5.0, seed=13)
+    first = mis2(Graph(g), engine="compacted_resident")
+    (launch,) = _find(first.provenance.span, "mis2.launch")
+    assert launch["metrics"].get("jit.compiles", 0) >= 1
+    again = mis2(Graph(g), engine="compacted_resident")
+    (launch,) = _find(again.provenance.span, "mis2.launch")
+    assert launch["metrics"].get("jit.compiles", 0) == 0
+    assert "jit.compiles" not in again.provenance.metrics
+
+
+def test_every_facade_entry_stays_under_the_span_series_cap():
+    from repro.api import (
+        amg,
+        amg_setup_batch,
+        cluster_gs_setup,
+        coarsen_batch,
+        color_batch,
+        mis2_batch,
+        misk,
+        partition,
+    )
+    from repro.obs.registry import MAX_SERIES_PER_METRIC
+
+    g = Graph(laplace3d(6).graph)
+    m = Graph(laplace3d(6))
+    small = dict(coarse_size=24, max_levels=3)
+    mis2(g)
+    mis2(g, engine="compacted_resident")
+    mis2(g, engine="pallas_hybrid")
+    misk(g, k=3)
+    color(g)
+    coarsen(g)
+    partition(g, 4)
+    amg_setup(m, **small)
+    amg(m, **small)
+    cluster_gs_setup(m)
+    mis2_batch([g, g])
+    color_batch([g, g])
+    coarsen_batch([g, g])
+    amg_setup_batch([m, m], **small)
+    names = {dict(s.labels)["span"] for s in obs.snapshot()
+             if s.name == "span.seconds"}
+    assert len(names) < MAX_SERIES_PER_METRIC
+    # the program's own spans (this file's tests open a few more) leave
+    # half the cap free
+    program = {n for n in names if n.startswith(
+        ("api.", "graph.", "mis2.", "coarsen.", "multilevel.", "serve."))}
+    assert {"api.mis2", "mis2.launch", "graph.to_device"} <= program
+    assert len(program) <= MAX_SERIES_PER_METRIC // 2, sorted(program)
+
+
+def record_cpu_trace(logdir, body) -> dict:
+    """``body()`` inside a ``bench.window`` annotation, profiled as the
+    harness profiles a window (no Python tracer), as in
+    ``bench/tests/test_bench_trace.py``; the loaded planes."""
+    import jax
+
+    from bench import trace as tr
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            body()
+    finally:
+        jax.profiler.stop_trace()
+    return tr.load(logdir)
+
+
+def test_spans_are_profiler_annotations_on_the_harness_clock(tmp_path):
+    from bench import harness
+    from bench import trace as tr
+
+    g = random_uniform_graph(400, 5.0, seed=14)
+    mis2(Graph(g), engine="compacted_resident")      # warm
+    t0 = []
+
+    def call():
+        t0.append(time.perf_counter())
+        mis2(Graph(g), engine="compacted_resident")
+
+    planes = record_cpu_trace(tmp_path, call)
+    (root,) = [s for s in obs.recent_spans(8) if s.start_s >= t0[0]]
+    mapped = harness.span_intervals([root], t0[0], tr.window_of(planes)[0])
+    native = {}
+    for lines in planes.values():
+        for evs in lines.values():
+            for name, start, _ in evs:
+                native.setdefault(name, []).append(start)
+    assert {"api.mis2", "graph.to_device", "mis2.launch", "mis2.wait",
+            "mis2.pull"} <= {name for name, _, _ in mapped}
+    for name, start, _ in mapped:
+        assert name in native, name
+        off = min(abs(s - start) for s in native[name])
+        assert off < 1_000_000, f"{name}: {off} ns off the trace clock"
+
+
+# ---------------------------------------------------------------------------
 # benchmark trajectory contract (satellite: records embed the snapshot)
 # ---------------------------------------------------------------------------
 

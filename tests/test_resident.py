@@ -1,7 +1,7 @@
 """Device-resident MIS-2 hot loop (ISSUE 4): digest parity with the
 host-driven engines across the full option matrix, zero host round-trips
 inside the fixed point (one dispatch per solve), fused Pallas pass
-bit-exactness, the ELL row-traffic model, and the jit-churn accounting."""
+bit-exactness, the fused passes' structure, and the jit-churn accounting."""
 import numpy as np
 import pytest
 
@@ -141,7 +141,7 @@ def test_compact_worklist_matches_flatnonzero():
 
 
 # ---------------------------------------------------------------------------
-# fused Pallas passes: bit-exact vs oracles, single-row-read traffic model
+# fused Pallas passes: bit-exact vs oracles, indices in (no row copies)
 # ---------------------------------------------------------------------------
 
 def _fused_inputs(v=700, deg=7.0, seed=3):
@@ -197,19 +197,8 @@ def test_fused_decide_bit_exact(count_frac):
     assert (np.asarray(out_k)[:count] == np.asarray(out_r)[:count]).all()
 
 
-def test_ell_row_traffic_model():
-    """The fused passes read each live row's ELL entries exactly once and
-    materialize no worklist copy; the host-driven pipeline moves the same
-    row data through HBM three times per pass."""
-    from repro.kernels.minprop_ell import ops
-
-    assert ops.ELL_ROW_TRAFFIC["pallas_resident"] == {"reads": 1, "writes": 0}
-    assert ops.ell_row_movements("pallas") == 3 * ops.ell_row_movements(
-        "pallas_resident")
-
-
 def test_fused_wrappers_take_indices_not_gathered_rows():
-    """Structural guarantee behind the traffic model: the fused wrappers
+    """Structural guarantee of the single row read: the fused wrappers
     consume worklist indices + the adjacency (the row gather traces into
     the same program as the kernel), not pre-gathered ``[W, D]`` row
     copies like the legacy pair."""
