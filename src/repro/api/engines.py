@@ -87,10 +87,11 @@ def _mis2_pallas(graph, active, options, backend: Backend):
 
 @register_engine("mis2", "compacted_resident",
                  doc="device-resident §V-B fixed point: one jitted "
-                     "while_loop per solve, worklists compacted on device "
-                     "(cumsum stream compaction), zero host round-trips — "
-                     "bit-identical to 'compacted'; the facade default on "
-                     "accelerators")
+                     "while_loop per solve, the update sets as masks over "
+                     "full-width rounds (two neighbor gathers a round on "
+                     "packed ELL; index worklists on the unpacked branch), "
+                     "zero host round-trips — bit-identical to "
+                     "'compacted'; the facade default on accelerators")
 def _mis2_compacted_resident(graph, active, options, backend: Backend):
     return _mis2_resident_impl(graph, active, _opts(options), pallas=False,
                                interpret=backend.resolve_interpret())

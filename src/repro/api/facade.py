@@ -82,8 +82,8 @@ def mis2(graph, *, active=None, options: Optional[Mis2Options] = None,
     sets (equal ``digest``) for equal options.
 
     ``engine=None`` auto-selects: the device-resident engines (one jitted
-    dispatch per solve, worklists compacted on device) on accelerators,
-    the host-driven ``compacted`` driver on CPU hosts;
+    dispatch per solve, no host round-trip inside the fixed point) on
+    accelerators, the host-driven ``compacted`` driver on CPU hosts;
     ``Backend(pallas=True)`` upgrades either to its Pallas variant.  When
     the graph's padded-ELL bytes estimate exceeds
     ``repro.graphs.hybrid.HYBRID_AUTO_BYTES`` (skewed degree distribution

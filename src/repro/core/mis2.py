@@ -12,16 +12,18 @@ Three execution strategies, bit-identical results:
   compiled step sizes.  This is the legacy host-driven path and the engine
   behind the Fig. 2 ablation.
 * ``compacted_resident`` / ``pallas_resident`` — the production hot loop:
-  the *same* per-round passes as ``mis2_compacted``, but the whole fixed
-  point is one jitted ``lax.while_loop`` over fixed ``[V]``-shaped state.
-  Worklists are compacted **on device** (cumsum-based stream compaction
-  producing ``(indices[V], count)`` pairs; dead slots hold the sentinel
-  ``V`` and are scatter-dropped), and the live ``count`` feeds the Pallas
-  ``pl.when`` block-skip logic instead of a host-side ``len(wl)``.  Zero
-  host round-trips inside the fixed point, one dispatch per solve, no jit
-  churn across worklist sizes — and results stay bit-identical to the
-  host-driven engines (enforced by the digest-parity matrix in
-  ``tests/test_resident.py``).
+  the *same* per-round update sets as ``mis2_compacted``, but the whole
+  fixed point is one jitted ``lax.while_loop`` over fixed ``[V]``-shaped
+  state.  The packed XLA ELL branch applies the sets as ``[V]`` masks over
+  full-width rounds (two neighbor gathers a round, no scatter); the Pallas
+  and unpacked branches compact index worklists **on device**
+  (cumsum-based stream compaction producing ``(indices[V], count)``
+  pairs; dead slots hold the sentinel ``V`` and are scatter-dropped), and
+  the live ``count`` feeds the Pallas ``pl.when`` block-skip logic instead
+  of a host-side ``len(wl)``.  Zero host round-trips inside the fixed
+  point, one dispatch per solve, no jit churn across worklist sizes — and
+  results stay bit-identical to the host-driven engines (enforced by the
+  digest-parity matrix in ``tests/test_resident.py``).
 
 The Fig. 2 optimization chain is exposed through ``Mis2Options`` — each knob
 is one of the paper's four optimizations:
@@ -104,8 +106,9 @@ class Mis2Result:
 # dense (fully jitted) engine — packed tuples, ELL layout
 # ===========================================================================
 
-def mis2_dense_fixed_point(neighbors: jnp.ndarray, active: jnp.ndarray,
-                           b: jnp.ndarray, priority: str = "xorshift_star",
+def mis2_dense_fixed_point(neighbors: jnp.ndarray,
+                           active: Optional[jnp.ndarray], b: jnp.ndarray,
+                           priority: str = "xorshift_star",
                            max_iters: int = MAX_ITERS_DEFAULT):
     """Mask-aware MIS-2 fixed point over one (possibly padded) graph.
 
@@ -122,33 +125,44 @@ def mis2_dense_fixed_point(neighbors: jnp.ndarray, active: jnp.ndarray,
     advances while this graph still has undecided vertices — under vmap a
     converged graph stops counting (and its state is a fixed point of
     ``body``) while its bucket mates continue.
+
+    A round makes two neighbor gathers (``t`` and ``m``) through the
+    slot-major ``[D, V]`` index block and no scatter; the undecided set is
+    §V-B's wl1 as a mask.  ``active`` is loop-invariant, so its per-slot
+    view is gathered once per solve; ``active=None`` (every vertex
+    active) skips that table.
     """
     v = neighbors.shape[0]
     vids = jnp.arange(v, dtype=jnp.uint32)
     prio_fn = PRIORITY_FNS[priority]
+    nbT = neighbors.T                                    # [D, V]
+    slot_active = None if active is None else active[nbT]
 
     # inactive vertices are invisible: T pinned to OUT, never refreshed
-    t0 = jnp.where(active, jnp.uint32(1), OUT)
+    t0 = jnp.ones(v, dtype=jnp.uint32) if active is None \
+        else jnp.where(active, jnp.uint32(1), OUT)
 
     def cond(state):
         t, it = state
-        return jnp.any(is_undecided(t) & active) & (it < max_iters)
+        return jnp.any(is_undecided(t)) & (it < max_iters)
 
     def body(state):
         t, it = state
-        und = is_undecided(t) & active
+        und = is_undecided(t)
         live = jnp.any(und)
         # refresh row (§V-A)
         t = jnp.where(und, pack(prio_fn(it, vids), vids, b), t)
         # refresh column: closed-neighborhood min (§V-D layout)
-        tn = xla_ops.gather_rows(t, neighbors)           # [V, D]
-        m = jnp.min(tn, axis=1)
+        m = jnp.min(t[nbT], axis=0)
         m = jnp.where(m == IN, OUT, m)          # IN-adjacent poison
-        # decide (distance-2 via neighbors' minima)
-        mn = xla_ops.gather_rows(m, neighbors)           # [V, D]
-        an = xla_ops.gather_rows(active, neighbors)
-        any_out = jnp.any(jnp.where(an, mn, IN) == OUT, axis=1)
-        all_eq = jnp.all(jnp.where(an, mn, t[:, None]) == t[:, None], axis=1)
+        # decide (distance-2 via neighbors' minima); inactive slots are
+        # neither OUT nor unequal
+        mn = m[nbT]
+        out, eq = mn == OUT, mn == t
+        if slot_active is not None:
+            out, eq = out & slot_active, eq | ~slot_active
+        any_out = jnp.any(out, axis=0)
+        all_eq = jnp.all(eq, axis=0)
         t = jnp.where(und & any_out, OUT, t)
         t = jnp.where(und & ~any_out & all_eq, IN, t)
         return t, it + live.astype(jnp.uint32)
@@ -173,17 +187,13 @@ def _mis2_dense_impl(graph, active: Optional[jnp.ndarray] = None,
                      options: Optional[Mis2Options] = None) -> Mis2Result:
     options = Mis2Options() if options is None else options
     ell = as_graph(graph).ell
-    v = ell.num_vertices
-    if active is None:
-        active = jnp.ones(v, dtype=bool)
-    else:
+    if active is not None:
         active = jnp.asarray(active)
     t, iters = mis2_dense_jittable(ell.neighbors, active,
                                    options.priority, options.max_iters)
     t_np = np.asarray(t)
-    act_np = np.asarray(active)
-    undecided = is_undecided(t_np) & act_np
-    return Mis2Result(t_np == np.uint32(IN), int(iters), not undecided.any())
+    return Mis2Result(t_np == np.uint32(IN), int(iters),
+                      not is_undecided(t_np).any())
 
 
 # ===========================================================================
@@ -317,6 +327,10 @@ HOTLOOP_STATS = HotLoopStats()
 #: rounds run by the resident fixed points, labelled by layout
 #: (``ell`` | ``csr_segment`` | ``hybrid``)
 ROUNDS = "mis2.rounds"
+
+#: packed-ELL ``compacted_resident`` solves given a caller's ``active``
+#: mask, whose per-slot table is gathered once per solve, before the loop
+ACTIVE_FOLDS = "mis2.active_folds"
 
 
 # ===========================================================================
@@ -621,7 +635,8 @@ def _mis2_compacted_impl(graph, active: Optional[np.ndarray] = None,
 
 # ===========================================================================
 # device-resident engine: the whole §V-B fixed point is ONE jitted
-# lax.while_loop — worklists compacted on device, zero host round-trips
+# lax.while_loop — update sets as masks or on-device worklists, zero host
+# round-trips
 # ===========================================================================
 
 def compact_worklist(mask: jnp.ndarray):
@@ -648,21 +663,32 @@ def _resident_ell_fixed_point(neighbors, active, *, priority: str,
                               packed: bool, max_iters: int, b: int,
                               use_pallas: bool = False,
                               interpret: Optional[bool] = None):
-    """Device-resident compacted fixed point, ELL layout.
+    """Device-resident fixed point, ELL layout: one ``lax.while_loop``, a
+    single dispatch per solve, the same sets and rounds as the host-driven
+    driver.
 
-    Identical per-round passes to the host-driven driver (same step
-    kernels, same ``[V]``-sentinel worklist convention), but worklist
-    rebuilding happens on device via :func:`compact_worklist` and the whole
-    loop is one ``lax.while_loop`` — a single dispatch per solve.  With
-    ``use_pallas`` the round runs the *fused* Pallas passes
-    (``kernels.minprop_ell.ops.fused_refresh_columns`` / ``fused_decide``):
-    the §V-A rank packing is recomputed on the fly from the gathered
-    neighbor ids, so no separate refresh_rows pass runs and each round
-    reads the ELL rows once per pass, with the live ``count`` feeding the
-    ``pl.when`` block-skip logic.  ``interpret=None`` defers to the
-    platform policy (``kernels._interpret.resolve_interpret``).
+    The packed XLA branch runs :func:`mis2_dense_fixed_point`: the §V-B
+    update sets as ``[V]`` masks, two neighbor gathers a round, and no
+    per-slot mask table when ``active`` is ``None`` (every vertex
+    active).  The unpacked §V-C
+    ablation runs the host driver's step kernels over index worklists
+    (``[V]``-sentinel convention) rebuilt on device by
+    :func:`compact_worklist`.  With ``use_pallas`` the round runs the
+    *fused* Pallas passes (``kernels.minprop_ell.ops.fused_refresh_columns``
+    / ``fused_decide``) over those index worklists: the §V-A rank packing
+    is recomputed on the fly from the gathered neighbor ids, so no
+    separate refresh_rows pass runs and each round reads the ELL rows once
+    per pass, with the live ``count`` feeding the ``pl.when`` block-skip
+    logic.  ``interpret=None`` defers to the platform policy
+    (``kernels._interpret.resolve_interpret``).
     """
+    if packed and not use_pallas:
+        t, it = mis2_dense_fixed_point(neighbors, active, jnp.uint32(b),
+                                       priority, max_iters)
+        return t, it, jnp.sum(is_undecided(t), dtype=jnp.int32)
     v = neighbors.shape[0]
+    if active is None:
+        active = jnp.ones(v, dtype=bool)
     if use_pallas:
         from ..kernels._interpret import resolve_interpret
         from ..kernels.minprop_ell import ops as minprop_ops
@@ -692,19 +718,14 @@ def _resident_ell_fixed_point(neighbors, active, *, priority: str,
 
     def body(state):
         tup, wl1, n1, wl2, n2, it = state
-        if packed:
+        if packed:                                # the fused Pallas passes
             t, m = tup
-            if use_pallas:
-                m = minprop_ops.fused_refresh_columns(
-                    t, m, wl2, n2, neighbors, it, priority=priority, b=b,
-                    interpret=interpret)
-                t = minprop_ops.fused_decide(
-                    t, m, wl1, n1, neighbors, active, it, priority=priority,
-                    b=b, interpret=interpret)
-            else:
-                t = _refresh_rows_packed(t, wl1, it, priority, b)
-                m = _refresh_cols_packed_ell(t, m, wl2, neighbors)
-                t = _decide_packed_ell(t, m, wl1, neighbors, active)
+            m = minprop_ops.fused_refresh_columns(
+                t, m, wl2, n2, neighbors, it, priority=priority, b=b,
+                interpret=interpret)
+            t = minprop_ops.fused_decide(
+                t, m, wl1, n1, neighbors, active, it, priority=priority,
+                b=b, interpret=interpret)
             und = is_undecided(t)
             live = m != U32MAX
             tup = (t, m)
@@ -800,14 +821,21 @@ def _mis2_resident_impl(graph, active: Optional[np.ndarray] = None,
 
     Exactly one jitted dispatch per solve (counted in
     ``HOTLOOP_STATS.resident_dispatches``); the only device->host transfer
-    is the final result pull after the fixed point has converged.
+    is the final result pull after the fixed point has converged.  Both
+    engines update only §V-B's sets each round: the packed XLA ELL branch
+    runs :func:`mis2_dense_fixed_point`, which applies them as masks over
+    full-width rounds, the Pallas and unpacked branches as on-device index
+    worklists.  On that packed XLA branch a caller's ``active`` mask is
+    gathered into a per-slot table once per solve (counted in
+    ``mis2.active_folds``); ``active=None`` skips that table.
     """
     options = Mis2Options() if options is None else options
     if not options.worklists:
         raise ValueError(
-            "resident engines implement §V-B worklist compaction by "
-            "construction; use engine='dense' (masked lanes) or the "
-            "host-driven 'compacted' driver for the no-worklist ablation")
+            "resident engines always restrict each round to §V-B's "
+            "worklist sets (as masks or as index worklists); use "
+            "engine='dense' (masked lanes) or the host-driven 'compacted' "
+            "driver for the no-worklist ablation")
     gh = as_graph(graph)
     if pallas and not (options.layout == "ell" and options.packed):
         raise ValueError("pallas path requires packed tuples + ELL layout")
@@ -818,8 +846,7 @@ def _mis2_resident_impl(graph, active: Optional[np.ndarray] = None,
         v = gh.num_vertices
     else:
         raise ValueError(options.layout)
-    active_j = jnp.ones(v, dtype=bool) if active is None \
-        else jnp.asarray(active)
+    active_j = None if active is None else jnp.asarray(active)
     b = id_bits(v)
 
     with _obs_span("mis2.resident_fixed_point", layout=options.layout,
@@ -830,8 +857,12 @@ def _mis2_resident_impl(graph, active: Optional[np.ndarray] = None,
                     gh.ell.neighbors, active_j, priority=options.priority,
                     packed=options.packed, max_iters=options.max_iters, b=b,
                     use_pallas=pallas, interpret=interpret)
+                if options.packed and not pallas and active is not None:
+                    _OBS.counter(ACTIVE_FOLDS).inc()
             else:
                 edge_rows, edge_cols = gh.csr_edges
+                if active_j is None:
+                    active_j = jnp.ones(v, dtype=bool)
                 t, it, n1 = _resident_csr_fixed_point(
                     edge_rows, edge_cols, active_j, priority=options.priority,
                     packed=options.packed, max_iters=options.max_iters, b=b,
@@ -869,7 +900,8 @@ def run_mis2(graph, active=None, options: Optional[Mis2Options] = None,
     """Warning-free engine dispatch used by ``repro.api`` and by the other
     core pipelines (aggregation, partitioning).  Engines ``'compacted'``
     (host-driven §V-B worklists), ``'compacted_resident'`` (the same fixed
-    point as one jitted ``while_loop`` with on-device worklist compaction),
+    point as one jitted ``while_loop``: masked full-width rounds on packed
+    ELL, on-device index worklists on the unpacked ablation),
     ``'dense'`` (single jitted ``while_loop`` over masks), ``'pallas'`` /
     ``'pallas_resident'`` (the Pallas min-propagation kernels on the
     measured hot loop; the resident variant runs the fused single-row-read

@@ -139,8 +139,7 @@ class StreamSession:
     def _solve_scratch(self, gh: Graph) -> Mis2Result:
         t0 = time.perf_counter()
         t, iters = mis2_dense_jittable(
-            gh.ell.neighbors, jnp.ones(self._v, dtype=bool),
-            self.options.priority, self.options.max_iters)
+            gh.ell.neighbors, None, self.options.priority, self.options.max_iters)
         t_np = np.asarray(t)
         if is_undecided(t_np).any():
             raise RuntimeError("MIS-2 fixed point hit max_iters during "

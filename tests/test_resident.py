@@ -16,29 +16,73 @@ from repro.graphs import csr_from_coo, laplace3d, random_uniform_graph
 PRIORITIES = ("fixed", "xorshift", "xorshift_star")
 
 
+def _torus(a, b):
+    """4-regular a x b torus without self loops: every ELL row is full,
+    so no row holds its own vertex, not even as padding."""
+    i = np.arange(a * b)
+    x, y = i % a, i // a
+    cols = np.concatenate([(x + 1) % a + y * a, (x - 1) % a + y * a,
+                           x + (y + 1) % b * a, x + (y - 1) % b * a])
+    return csr_from_coo(np.tile(i, 4), cols, a * b)
+
+
 def graph_cases():
     return {
         "laplace3d": Graph(laplace3d(8).graph),            # V = 512
+        # varying degree: short rows are self-padded in the ELL layout
         "er_random": Graph(random_uniform_graph(600, 5.0, seed=21)),
         # PR 3's adversarial size: 1022 straddles the 1024 pow2 boundary
         "er_1022": Graph(random_uniform_graph(1022, 6.0, seed=9)),
+        "no_self_loops": Graph(_torus(24, 25)),
+        "single": Graph(csr_from_coo(np.array([0]), np.array([0]), 1)),
     }
+
+
+def active_mask(kind, v):
+    """``None`` (no caller mask), a partial mask, or no active vertex."""
+    if kind == "all":
+        return None
+    if kind == "none":
+        return np.zeros(v, dtype=bool)
+    return np.random.default_rng(0).random(v) < 0.6
 
 
 # ---------------------------------------------------------------------------
 # digest-parity matrix: resident vs host-driven vs dense
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("priority", PRIORITIES)
-def test_resident_parity_priorities(priority):
-    g = graph_cases()["laplace3d"]
+# the first three keep their ids; the rest run the masked resident round
+# (``compacted_resident``) against the host driver and ``dense`` on short
+# self-padded rows, rows without their own vertex (not a MIS-2 input, but
+# every engine must still agree) and V = 1, with no caller mask, a
+# partial one and an empty one
+PARITY_CASES = [pytest.param("laplace3d", "all", p, id=p)
+                for p in PRIORITIES] + [
+    pytest.param(g, kind, p, id=f"{g}-{kind}-{p}")
+    for g, kinds in (("er_random", ("all", "partial", "none")),
+                     ("no_self_loops", ("all", "partial", "none")),
+                     ("single", ("all", "none")))
+    for kind in kinds for p in PRIORITIES]
+
+
+@pytest.mark.parametrize("gname,mask,priority", PARITY_CASES)
+def test_resident_parity_priorities(gname, mask, priority):
+    g = graph_cases()[gname]
+    active = active_mask(mask, g.num_vertices)
     opts = Mis2Options(priority=priority)
-    ref = mis2(g, options=opts, engine="compacted")
-    verify_mis2(g.csr, ref.in_set)
-    for eng in ("compacted_resident", "pallas_resident", "dense"):
-        r = mis2(g, options=opts, engine=eng)
+    ref = mis2(g, active=active, options=opts, engine="compacted")
+    if active is None and gname != "no_self_loops":
+        verify_mis2(g.csr, ref.in_set)       # MIS-2 needs closed rows
+    elif mask == "partial":                  # inactive slots of live rows
+        nb = np.asarray(g.ell.neighbors)
+        assert (active[:, None] & ~active[nb]).any()
+    engines = ("compacted_resident", "pallas_resident", "dense") \
+        if gname == "laplace3d" else ("compacted_resident", "dense")
+    for eng in engines:
+        r = mis2(g, active=active, options=opts, engine=eng)
         assert r.digest == ref.digest, (priority, eng)
         assert r.iterations == ref.iterations, (priority, eng)
+        assert r.converged == ref.converged, (priority, eng)
 
 
 @pytest.mark.parametrize("layout", ["ell", "csr_segment"])
@@ -106,6 +150,43 @@ def test_resident_zero_host_syncs_one_dispatch():
         mis2(g, engine="pallas_resident")
     assert cap.value("mis2.host_syncs") == 0
     assert cap.value("mis2.resident_dispatches") == 1
+
+
+@pytest.mark.parametrize("caller_mask,gathers", [(False, 2), (True, 3)])
+def test_masked_round_gathers_no_worklists(caller_mask, gathers):
+    """The packed XLA resident program gathers ``t`` and ``m`` once each a
+    round (plus the mask's per-slot table once per solve) and scatters
+    nothing: no worklist indirection.  Its module keeps ``fixed_point`` in
+    its name, which the benchmark's roofline reader matches."""
+    import importlib
+    import re
+
+    from repro.graphs import csr_to_ell_graph
+
+    mis2_mod = importlib.import_module("repro.core.mis2")
+    nb = csr_to_ell_graph(laplace3d(6).graph).neighbors
+    active = jnp.ones(nb.shape[0], dtype=bool) if caller_mask else None
+    lowered = mis2_mod._resident_ell_fixed_point.lower(
+        nb, active, priority="xorshift_star", packed=True, max_iters=128,
+        b=8)
+    ops = re.findall(r"=\s*\S+\s+(gather|scatter)\(",
+                     lowered.compile().as_text())
+    assert ops.count("gather") == gathers and "scatter" not in ops, ops
+    module = lowered.compiler_ir("stablehlo").operation.attributes[
+        "sym_name"].value
+    assert "fixed_point" in module, module
+
+
+def test_active_folds_counts_caller_masks():
+    g = graph_cases()["laplace3d"]
+    with obs.capture() as cap:
+        mis2(g, engine="compacted_resident")
+    assert cap.value("mis2.active_folds") == 0
+    with obs.capture() as cap:
+        r = coarsen(g, mis2_engine="compacted_resident")
+    assert (r.phase == 2).any()                  # phase 2 ran, with a mask
+    assert cap.value("mis2.active_folds") == 1
+    assert cap.value("mis2.resident_dispatches") == 2
 
 
 def test_host_driven_engine_pays_syncs_every_round():

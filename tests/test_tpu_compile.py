@@ -121,15 +121,21 @@ def test_spmv_pair_compiles(sd, which):
     _assert_kernel(text)
 
 
-@pytest.mark.parametrize("use_pallas", [True, False])
-def test_resident_fixed_point_compiles(sd, use_pallas):
+@pytest.mark.parametrize("use_pallas,caller_mask", [
+    pytest.param(True, True, id="True"),
+    pytest.param(False, True, id="False"),
+    pytest.param(False, False, id="False-no_caller_mask")])
+def test_resident_fixed_point_compiles(sd, use_pallas, caller_mask):
     from repro.core.mis2 import _resident_ell_fixed_point
 
-    text = _compile(
-        lambda n, a: _resident_ell_fixed_point(
-            n, a, priority="xorshift_star", packed=True, max_iters=128,
-            b=B, use_pallas=use_pallas, interpret=False),
-        sd((V, D), jnp.int32), sd((V,), jnp.bool_))
+    def fixed_point(neighbors, active=None):
+        return _resident_ell_fixed_point(
+            neighbors, active, priority="xorshift_star", packed=True,
+            max_iters=128, b=B, use_pallas=use_pallas, interpret=False)
+
+    args = (sd((V, D), jnp.int32),) + ((sd((V,), jnp.bool_),)
+                                       if caller_mask else ())
+    text = _compile(fixed_point, *args)
     assert ("tpu_custom_call" in text) == use_pallas
 
 
