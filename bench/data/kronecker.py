@@ -17,6 +17,9 @@ import numpy as np
 
 from .csr import closed_symmetric
 
+#: configuration overrides of the benchmark's CPU tests
+TINY = {"scale": 9}
+
 
 def kronecker_edges(scale: int, edge_factor: int, a: float, b: float,
                     c: float, rng):

@@ -14,6 +14,10 @@ import numpy as np
 
 from .csr import csr_from_pairs
 
+#: configuration overrides of the benchmark's CPU tests: a grid small
+#: enough for the Pallas interpreter, with three unequal axes
+TINY = {"nx": 9, "ny": 8, "nz": 7}
+
 OFFSETS_7PT = ((-1, 0, 0), (1, 0, 0), (0, -1, 0), (0, 1, 0), (0, 0, -1),
                (0, 0, 1))
 

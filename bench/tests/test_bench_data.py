@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from . import _paths  # noqa: F401
+from .. import harness
 from ..data import kronecker, laplace3d
 from ..data.ref_coarsen import coarsen_reference
 from ..data.ref_mis2 import (
@@ -57,12 +58,25 @@ def test_kronecker_seeded():
     assert np.array_equal(np.sort(np.diff(a[0])), np.sort(np.diff(c[0])))
     d = kronecker.generate(dict(KRON, generator_seed=2), 5)
     assert not np.array_equal(np.sort(np.diff(a[0])), np.sort(np.diff(d[0])))
-    indptr, indices = a
-    v = len(indptr) - 1
-    rows = np.repeat(np.arange(v), np.diff(indptr))
-    adj = closed_adjacency(indptr, indices)
-    assert (adj != adj.T).nnz == 0                      # symmetric
-    assert (indices[rows == indices].size == v)         # every self loop
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in
+                                    harness.benchmark()["configs"]])
+def test_generator_gives_closed_symmetric_csr(config):
+    """Every configuration's generator, at the ``TINY`` size it states for
+    the CPU tests, gives what the references assume: columns sorted
+    within each row, a symmetric pattern and every self loop."""
+    cfg = harness.config(config)
+    small = dict(cfg, **harness.module("data", cfg["generator"]).TINY)
+    for seed in (None, 1, 2**31 + 11):
+        indptr, indices = harness.generate(small, seed)
+        v = len(indptr) - 1
+        rows = np.repeat(np.arange(v), np.diff(indptr))
+        step = np.diff(indices.astype(np.int64))
+        assert (step[rows[1:] == rows[:-1]] > 0).all()      # sorted, unique
+        adj = closed_adjacency(indptr, indices)
+        assert (adj != adj.T).nnz == 0                      # symmetric
+        assert np.array_equal(rows[rows == indices], np.arange(v))  # loops
 
 
 def test_priority_hash_matches_program():

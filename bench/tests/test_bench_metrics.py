@@ -62,32 +62,27 @@ def test_none_where_the_spans_never_opened(metric):
 PROGRAM_METRICS = ("copy_ms", "launch_ms", "round_ms", "join_ms")
 
 
-@pytest.mark.parametrize("cell", [c["name"] for c in harness.benchmark()[
-    "workloads"]])
-def test_traced_run_reports_the_program_metrics(monkeypatch, cell):
-    """A whole traced run on the CPU at a tiny size, on the engine the
-    chip runs: every metric of the program's spans its cell lists is
-    read (the device-trace metrics find no chip here)."""
-    import time
+def check_traced_run(monkeypatch, cell, spec):
+    """A whole traced run on the CPU at the cell's tiny size, on the
+    engine the chip runs: every metric of the program's spans that the
+    cell lists is read (the device-trace metrics find no chip here)."""
+    from .test_bench_faults import on_chip_path, run, tiny
 
-    import jax
-
-    from .test_bench_faults import SPEC, on_chip_path, tiny
-
-    entry, cfg = tiny(cell)
+    entry, cfg = tiny(cell, spec)
     on_chip_path(monkeypatch, cfg)
-    monkeypatch.setattr(harness, "find_devices", lambda chips: jax.devices())
-    monkeypatch.setattr(harness, "peaks", lambda kind: None)
-    monkeypatch.setattr(harness, "enable_compile_cache", lambda: None)
-    out = harness.run_cell(cell, cfg, harness.traffic(entry["traffic"]),
-                           2**31 + 11, 0.2, True, time.perf_counter(),
-                           spec=SPEC)
+    out = run(monkeypatch, cell, cfg, entry, trace=True, spec=spec)
     assert out["correct"]
-    want = {m["name"] for m in harness.cell_metrics(SPEC, cell, "per_layer")
+    want = {m["name"] for m in harness.cell_metrics(spec, cell, "per_layer")
             if m["name"] in PROGRAM_METRICS}
     assert want >= {"copy_ms", "launch_ms", "round_ms"}
     assert want <= set(out["metrics"])
     assert all(out["metrics"][m]["value"] > 0 for m in want)
+
+
+@pytest.mark.parametrize("cell", [c["name"] for c in harness.benchmark()[
+    "workloads"]])
+def test_traced_run_reports_the_program_metrics(monkeypatch, cell):
+    check_traced_run(monkeypatch, cell, harness.benchmark())
 
 
 @pytest.mark.parametrize("name", ["_resident_ell_fixed_point",
